@@ -1,39 +1,32 @@
 """Shard-count scaling of the distributed fused pipeline (dist/knn.py).
 
-Runs the measurement in a SUBPROCESS with 8 forced host devices — the same
-device-count isolation rule as tests/dist_checks.py: jax locks the device
-count at first backend init, so the benchmarking session must keep its
-1-device view.  Meshes of 1/2/4/8 shards are carved from the 8-device
-backend; queries-per-second per shard count shows how the per-shard
-filter/prune/refine cost amortizes (on host CPU the collectives are
-memcpys, so this tracks the partitioning overhead floor, not ICI).
+Runs in the benchmark's own process on the devices that exist: meshes of
+1, 2, 4, ... shards are carved from ``jax.devices()``, so a one-device
+host measures the 1-shard program only.  A child process could not share
+the chip with a parent that has touched JAX; the forced-multi-device CPU
+rehearsal of the same path lives in tests/dist_checks.py.  Queries per
+second per shard count show how the per-shard filter/prune/refine cost
+amortizes.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.core.bregman import get_family
+from repro.core.index import build_index
+from repro.dist import knn as dknn
+from repro.dist.sharding import make_mesh
 
 from .common import Row
 
-_SCRIPT = textwrap.dedent("""
-    import os, json, time
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                               + os.environ.get("XLA_FLAGS", ""))
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import sys
-    sys.path.insert(0, %(src)r)
-    import numpy as np
-    import jax, jax.numpy as jnp
-    from repro.core.bregman import get_family
-    from repro.core.index import build_index
-    from repro.dist import knn as dknn
-    from repro.dist.sharding import make_mesh
 
-    n, d, m, k, q = %(n)d, 64, 8, 10, 64
+def run(scale: float = 1.0):
+    n, d, m, k, q = max(512, int(8192 * scale)), 64, 8, 10, 64
     fam = get_family("squared_euclidean")
     data = np.asarray(fam.sample(jax.random.PRNGKey(0), (n, d), scale=1.0))
     ys = jnp.asarray(np.asarray(
@@ -41,48 +34,33 @@ _SCRIPT = textwrap.dedent("""
     forest = build_index(data, "squared_euclidean", m=m, num_clusters=64,
                          seed=0)
     budget = max(2 * k, n // 16)
-    out = []
-    for shards in (1, 2, 4, 8):
+    yv = dknn.query_subview(forest.partition, ys)
+    records = []
+    shards = 1
+    while shards <= len(jax.devices()):
         mesh = make_mesh((shards,), ("data",),
                          devices=jax.devices()[:shards])
         sharded = dknn.shard_index(forest, mesh)
-        yv = dknn.query_subview(forest.partition, ys)
-        run = lambda: jax.block_until_ready(dknn.distributed_knn(
-            sharded, yv, family="squared_euclidean", k=k, budget=budget,
-            mesh=mesh).ids)
-        run()                                    # compile + warm
+
+        def once():
+            return jax.block_until_ready(dknn.distributed_knn(
+                sharded, yv, family="squared_euclidean", k=k,
+                budget=budget, mesh=mesh).ids)
+
+        once()                                   # compile + warm
         times = []
         for _ in range(5):
             t0 = time.perf_counter()
-            run()
+            once()
             times.append(time.perf_counter() - t0)
-        us = float(np.median(times) * 1e6)
-        out.append({"shards": shards, "us": us,
-                    "qps": round(q / (us / 1e6), 1)})
-    print("RESULT " + json.dumps(out))
-""")
-
-
-def run(scale: float = 1.0):
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    n = max(512, int(8192 * scale))
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-c", _SCRIPT % {"src": src, "n": n}],
-        env=env, capture_output=True, text=True, timeout=900)
-    if proc.returncode != 0:
-        raise RuntimeError("dist bench subprocess failed:\n"
-                           f"{proc.stderr[-2000:]}")
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("RESULT ")][0]
-    records = json.loads(line[len("RESULT "):])
-    base_us = records[0]["us"]
-    return [Row("dist_knn", f"shards{r['shards']}", r["us"],
-                {"n": n, "qps": r["qps"],
-                 "vs_1shard": round(base_us / r["us"], 2)})
-            for r in records]
+        records.append((shards, float(np.median(times) * 1e6)))
+        shards *= 2
+    base_us = records[0][1]
+    return [Row("dist_knn", f"shards{s}", us,
+                {"n": n, "qps": round(q / (us / 1e6), 1),
+                 "vs_1shard": round(base_us / us, 2),
+                 "platform": jax.devices()[0].platform})
+            for s, us in records]
 
 
 if __name__ == "__main__":

@@ -19,6 +19,8 @@ import json
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "bench_construction",    # Fig. 7
     "bench_partitions",      # Figs. 8-9
@@ -31,7 +33,7 @@ MODULES = [
     "bench_batch_search",    # fused batch pipeline vs vmapped per-query
     "bench_quantized",       # int8 tier: filter bytes moved + QPS vs fp32
     "bench_incremental",     # segmented insert/delete/compact vs rebuild
-    "bench_dist_knn",        # shard-count scaling (8 forced host devices)
+    "bench_dist_knn",        # shard-count scaling on the devices present
     "bench_retrieval",       # retrieval-service overhead (chaos: --chaos)
     "bench_kernels",         # kernel micro-benches
     "bench_kernel_roofline",  # fused vs unfused kernel HLO roofline terms
@@ -49,6 +51,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as JSON (the CI bench artifact)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     print("bench,name,us_per_call,derived")
     failures, all_rows, t_start = [], [], time.time()

@@ -1,9 +1,1 @@
-"""repro — BrePartition reproduction (core search, kernels, serving, dist).
-
-Importing any ``repro.*`` module pulls this in first, which installs the
-jax forward-compat aliases (``jax.shard_map`` / ``jax.sharding.AxisType``
-/ ``jax.make_mesh(axis_types=...)``) that the model and launch layers use
-unconditionally — see :mod:`repro.dist.compat`.
-"""
-
-from . import dist as _dist  # noqa: F401 — side effect: compat install
+"""repro — BrePartition reproduction (core search, kernels, serving, dist)."""

@@ -33,6 +33,12 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+# Precision of the f32 contractions exact search rests on: the Cauchy upper
+# bounds (filter and Alg.-4 bounds) and the refine cross term.  A TPU's
+# default f32 dot takes bf16 passes, which can pull an "upper" bound below
+# the true distance and cost the refine's split form its digits.
+F32_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def ub_components(p: dict, q: dict) -> Array:
     """Per-subspace upper bounds UB_i. Shapes broadcast: p (..., M), q (..., M)."""
@@ -55,7 +61,8 @@ def ub_matrix(p: dict, q: dict) -> Array:
     """
     bias_p = jnp.sum(p["alpha"], axis=-1)          # (n,)
     bias_q = jnp.sum(q["qconst"], axis=-1)         # (qn,)
-    cauchy = p["sqrt_gamma"] @ q["sqrt_delta"].T   # (n, qn) — the MXU matmul
+    cauchy = jnp.dot(p["sqrt_gamma"], q["sqrt_delta"].T,
+                     precision=F32_PRECISION)      # (n, qn) — the MXU matmul
     return bias_p[:, None] + bias_q[None, :] + cauchy
 
 
@@ -101,7 +108,7 @@ def refine_distance(x: Array, q: dict, family, y: Array | None = None) -> Array:
     grad = q["grad"]
     c_y = jnp.sum(q["_y_grad"], axis=-1) if "_y_grad" in q else q["c_y"]
     fx = jnp.sum(family.phi(x), axis=-1)
-    return fx - x @ grad + c_y
+    return fx - jnp.dot(x, grad, precision=F32_PRECISION) + c_y
 
 
 def query_refine_constants(y: Array, family) -> dict:

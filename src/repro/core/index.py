@@ -19,6 +19,8 @@ distance evaluation, and never prunes a true Theorem-3 candidate.
 from __future__ import annotations
 
 import dataclasses
+import logging
+import time
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,8 @@ from .clustering import kmeans, cluster_stats
 from . import quantize as qz
 
 Array = jax.Array
+
+logger = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -469,6 +473,7 @@ def build_index(
     dequantized points — identical ids/distances to an fp32 index built
     over ``rows_view()``.
     """
+    t0 = time.perf_counter()
     fam = get_family(family) if isinstance(family, str) else family
     data = jnp.asarray(data, dtype=jnp.float32)
     if quantize:
@@ -477,14 +482,17 @@ def build_index(
     n, d = data.shape
     data_np = np.asarray(data)
 
+    t_load = time.perf_counter()
     if m is None:
         m = fit_cost_model(data_np, fam, seed=seed).m_star()
     m = int(np.clip(m, 1, d))
+    t_cost = time.perf_counter()
 
     if pccp and m < d:
         part = build_pccp_partition(data_np, m, seed=seed)
     else:
         part = make_partition(d, m)
+    t_part = time.perf_counter()
 
     c = num_clusters or default_num_clusters(n)
     c = int(min(c, n))
@@ -501,10 +509,18 @@ def build_index(
             sub_views[:, i, :], mask[i], ki,
             family=fam, num_clusters=c, iters=kmeans_iters,
         )
+        # Number the clusters by their center's alpha, so the layout sort
+        # below puts clusters of like magnitude in adjacent rows.  With
+        # k-means' arbitrary labels every ENV_BLOCK_ROWS group mixed far
+        # apart clusters and no block envelope could skip anything.
+        perm = jnp.argsort(jnp.sum(fam.phi(cen) * mask[i], axis=-1))
+        cen, asg = cen[perm], jnp.argsort(perm).astype(jnp.int32)[asg]
         centers_list.append(cen)
         assign_list.append(asg)
     centers = jnp.stack(centers_list)               # (M, C, w)
     assign = jnp.stack(assign_list, axis=1)         # (n, M)
+    jax.block_until_ready(assign)
+    t_kmeans = time.perf_counter()
 
     # Shared layout: order points by the reference subspace's cluster id.
     order = jnp.argsort(assign[:, 0], stable=True)
@@ -587,6 +603,14 @@ def build_index(
     # directed-rounded corners it will serve, not the pre-encode fp32 ones
     # (whose floor-rounding could otherwise dip below the envelope).
     forest = refresh_envelopes(forest)
+    jax.block_until_ready(forest.env_alpha_min)
+    # Phase wall times (host cost model, host PCCP, device k-means) for
+    # bring-up and capacity planning; read via the log record's extra.
+    seconds = {"cost_model": t_cost - t_load, "pccp": t_part - t_cost,
+               "kmeans": t_kmeans - t_part,
+               "total": time.perf_counter() - t0}
+    logger.info("build_index n=%d M=%d C=%d: %s", n, m, c, seconds,
+                extra={"build_seconds": seconds})
     if calibrate:
         # Fit over the finished index (lazy import: calibrate drives the
         # search entry points, which import this module).

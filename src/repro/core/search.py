@@ -629,11 +629,14 @@ def _fill_block_slots(sel: Array, count: Array, admit: Array, off: Array,
     A block fills the contiguous slot range [count, count+tot); the row of
     within-block member rank r is found by binary search on the block's
     admit prefix-sum (the blockwise analogue of _compact_candidates'
-    searchsorted).  Only min(bn, budget) ranks can occur per block, so the
-    search is rank-limited and a budget-sized gather+select routes each
-    slot to its rank — no scatter anywhere (XLA CPU serializes scatters)
-    and no array longer than the block.  Factored out of the scan bodies
-    so the fused and unfused paths share slot semantics by construction.
+    searchsorted).  Only T = min(bn, budget) ranks can occur per block, so
+    each query reads, updates and writes back one T-wide window of its
+    slots, placed where the block's first slot falls (or flush with the
+    budget's end).  The window is a dynamic slice, the rank -> slot shift
+    a slice of the doubled rank table: per block the work is O(q * T),
+    never O(q * budget), and there is no per-element gather or scatter.
+    Factored out of the scan bodies so the fused and unfused paths share
+    slot semantics by construction.
     """
     bn = admit.shape[0]
     csum = jnp.cumsum(admit, axis=0)                     # (bn, q)
@@ -644,12 +647,22 @@ def _fill_block_slots(sel: Array, count: Array, admit: Array, off: Array,
         lambda c: jnp.searchsorted(c, ranks, side="left"))(csum.T)
     rows_for_rank = jnp.minimum(rows_for_rank,
                                 bn - 1).astype(jnp.int32)  # (q, T)
-    r0 = (jnp.arange(budget, dtype=jnp.int32)[None, :]
-          - count[:, None])                              # rank-1
-    fill = (r0 >= 0) & (r0 < tot[:, None])
-    rows_at_slot = jnp.take_along_axis(
-        rows_for_rank, jnp.clip(r0, 0, t_ranks - 1), axis=1)
-    sel = jnp.where(fill, off + rows_at_slot, sel)
+    lane = jnp.arange(t_ranks, dtype=jnp.int32)
+
+    def one_query(sel_q, rows_q, count_q, tot_q):
+        start = jnp.clip(count_q, 0, budget - t_ranks)
+        shift = count_q - start                          # >= 0
+        # window slot j holds member rank j - shift (0-based)
+        rank = lane - shift
+        fill = (rank >= 0) & (rank < tot_q)
+        doubled = jnp.concatenate([rows_q, rows_q])
+        rows_at = jax.lax.dynamic_slice(
+            doubled, (t_ranks - jnp.minimum(shift, t_ranks),), (t_ranks,))
+        window = jax.lax.dynamic_slice(sel_q, (start,), (t_ranks,))
+        window = jnp.where(fill, off + rows_at, window)
+        return jax.lax.dynamic_update_slice(sel_q, window, (start,))
+
+    sel = jax.vmap(one_query)(sel, rows_for_rank, count, tot)
     return sel, count + tot
 
 
@@ -854,9 +867,8 @@ def _stream_prune_compact(index: BallForest, qs: dict, qb: Array,
     return sel, valid, count, admitted, blocks_run, tau
 
 
-def _refine_batch(index: BallForest, qs: dict, sel: Array, valid: Array,
-                  k: int):
-    """One batched kernel call refines all queries' candidate rows.
+def _refine_dists(index: BallForest, grad: Array, c_y: Array, sel: Array):
+    """Exact distances of the candidate rows ``sel`` (q, budget) -> (q, budget).
 
     The int8 tier gathers candidate CODES (1 byte/coord) plus two decode
     scalars per row and runs the fused dequantize+refine kernel, so the
@@ -868,12 +880,41 @@ def _refine_batch(index: BallForest, qs: dict, sel: Array, valid: Array,
         codes = jnp.take(index.data, sel, axis=0)       # (q, budget, d) int8
         scale = jnp.take(index.data_scale, sel)         # (q, budget)
         zp = jnp.take(index.data_zp, sel)
-        dist = kernel_ops.bregman_refine_batch_quant(
-            codes, scale, zp, qs["grad"], qs["c_y"], index.family_name)
+        return kernel_ops.bregman_refine_batch_quant(
+            codes, scale, zp, grad, c_y, index.family_name)
+    rows = jnp.take(index.data, sel, axis=0)            # (q, budget, d)
+    return kernel_ops.bregman_refine_batch(rows, grad, c_y,
+                                           index.family_name)
+
+
+# Largest candidate-row gather one refine step materializes.  A batch whose
+# (q, budget, d) gather is larger is refined in query chunks: an escalated
+# budget near n would otherwise gather q copies of the table at once.  The
+# int8 tier's per-row decode scalars ride as (budget, 1) columns, which a
+# TPU pads to 128 lanes, so its true footprint is several times the code
+# bytes counted here; the cap leaves room for that.
+REFINE_GATHER_BYTES = 1 << 28
+
+
+def _refine_batch(index: BallForest, qs: dict, sel: Array, valid: Array,
+                  k: int):
+    """Batched kernel calls refine all queries' candidate rows, then top-k."""
+    q, budget = sel.shape
+    per_query = budget * index.d * index.data.dtype.itemsize
+    chunk = max(1, min(q, REFINE_GATHER_BYTES // per_query))
+    if chunk == q:
+        dist = _refine_dists(index, qs["grad"], qs["c_y"], sel)
     else:
-        rows = jnp.take(index.data, sel, axis=0)        # (q, budget, d)
-        dist = kernel_ops.bregman_refine_batch(
-            rows, qs["grad"], qs["c_y"], index.family_name)  # (q, budget)
+        nc = -(-q // chunk)
+
+        def chunked(a):
+            pad = ((0, nc * chunk - q),) + ((0, 0),) * (a.ndim - 1)
+            return jnp.pad(a, pad).reshape((nc, chunk) + a.shape[1:])
+
+        dist = jax.lax.map(
+            lambda a: _refine_dists(index, *a),
+            (chunked(qs["grad"]), chunked(qs["c_y"]), chunked(sel)))
+        dist = dist.reshape(nc * chunk, budget)[:q]
     dist = jnp.where(valid, dist, POS_BIG)
     neg, pos = jax.lax.top_k(-dist, k)                  # (q, k)
     ids = jnp.take(index.point_ids,

@@ -18,6 +18,7 @@ to find; real downloads are unavailable offline (DESIGN.md §7).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -131,7 +132,11 @@ def make_vectors(spec: VectorDatasetSpec, scale: float = 1.0,
     """
     n = max(int(spec.n * scale), 64)
     d = spec.d
-    rng = np.random.default_rng(seed + hash(spec.name) % (1 << 30))
+    # A stable digest of the name: str hash() is salted per process, which
+    # made the "same" seeded dataset differ between processes.
+    name_seed = int.from_bytes(
+        hashlib.blake2b(spec.name.encode(), digest_size=4).digest(), "little")
+    rng = np.random.default_rng(seed + name_seed % (1 << 30))
     if spec.name == "uniform":
         data = rng.uniform(0.0, 100.0, (n, d))
     elif spec.name == "normal":
@@ -147,8 +152,12 @@ def make_vectors(spec: VectorDatasetSpec, scale: float = 1.0,
         mix = rng.integers(0, k, n)
         factors = rng.normal(size=(k, d, rank)) / np.sqrt(rank)
         z = rng.normal(size=(n, rank))
-        data = centers[mix] + np.einsum("nr,ndr->nd", z, factors[mix]) \
-            + 0.1 * rng.normal(size=(n, d))
+        # Low-rank term per mixture component: one (rows, rank) x (rank, d)
+        # product each, never an (n, d, rank) gather (65 GB at Deep's n).
+        data = 0.1 * rng.normal(size=(n, d))
+        for c in range(k):
+            rows = mix == c
+            data[rows] += centers[c] + z[rows] @ factors[c].T
         data = np.abs(data) * scales[mix]
     fam = get_family(spec.measure)
     if fam.name in ("itakura_saito", "burg", "shannon"):

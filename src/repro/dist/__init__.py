@@ -12,12 +12,4 @@ Submodules:
 * :mod:`repro.dist.compression` — int8 gradient compression with error
   feedback.
 * :mod:`repro.dist.pipeline` — microbatch pipeline-parallel schedule.
-
-Importing the package installs the jax forward-compat aliases (see
-:mod:`repro.dist.compat`) so all of the above use one API spelling on
-old and new jax alike.
 """
-
-from . import compat as _compat
-
-_compat.install()

@@ -34,10 +34,6 @@ from typing import Any, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat as _compat
-
-_compat.install()
-
 Array = jax.Array
 
 # Logical axis -> candidate mesh axes, in claim-priority order (dict order
@@ -77,7 +73,7 @@ DECODE_RULES: dict[str, tuple[str, ...]] = dict(SERVE_RULES, seq=())
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices=None) -> Mesh:
-    """A mesh with Auto axis types on every jax version."""
+    """A mesh whose axes are all of type Auto."""
     return jax.make_mesh(
         tuple(shape), tuple(axes), devices=devices,
         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
@@ -174,15 +170,11 @@ def tree_shardings_for_structs(axes: Any, structs: Any, mesh: Mesh,
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """One shard_map spelling for old and new jax.
+    """``jax.shard_map`` with replication checking off by default.
 
     ``check=False`` by default: the dist substrates all produce
     value-replicated outputs via explicit collectives that replication
     inference cannot always see through (ring loops especially).
     """
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    except TypeError:  # pre-check_vma spelling
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.bounds import F32_PRECISION
 from repro.core.bregman import get_family
 from repro.core.quantize import DOMAIN_EPS, POSITIVE_FAMILIES
 
@@ -51,6 +52,13 @@ def bregman_refine(
         block_b=block_b, block_d=block_d, interpret=interpret)[0]
 
 
+def _cross(x, grad):
+    """(bb, bd) . (1, bd) -> (bb, 1): the refine's x . phi'(y) term."""
+    return jax.lax.dot_general(x, grad, (((1,), (1,)), ((), ())),
+                               precision=F32_PRECISION,
+                               preferred_element_type=jnp.float32)
+
+
 def _make_batch_kernel(phi):
     def kernel(rows_ref, grad_ref, mask_ref, acc_ref):
         j = pl.program_id(2)
@@ -60,11 +68,10 @@ def _make_batch_kernel(phi):
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         rows = rows_ref[0]                         # (bb, bd)
-        grad = grad_ref[...]                       # (1, bd) — this query's tile
+        grad = grad_ref[0]                         # (1, bd) — this query's tile
         mask = mask_ref[...]                       # (1, bd)
         fx = jnp.sum(phi(rows) * mask, axis=-1, keepdims=True)      # VPU
-        cross = jnp.dot(rows, grad.T, preferred_element_type=jnp.float32)
-        acc_ref[0] += fx - cross                   # (bb, 1)
+        acc_ref[0] += fx - _cross(rows, grad)      # (bb, 1)
 
     return kernel
 
@@ -99,7 +106,10 @@ def bregman_refine_batch(
     # grad padded with 0 so the matmul ignores them.
     safe = 1.0 if fam.name in ("itakura_saito", "burg", "shannon") else 0.0
     r = jnp.pad(rows, ((0, 0), (0, b_pad), (0, d_pad)), constant_values=safe)
-    g = jnp.pad(grad, ((0, 0), (0, d_pad)))
+    # Per-query operands carry a unit middle axis so each block's last two
+    # dims are (1, bd): a (1, bd) block of a (q, d) array breaks the TPU's
+    # (8, 128) tiling rule.
+    g = jnp.pad(grad, ((0, 0), (0, d_pad)))[:, None, :]
     mask = jnp.pad(jnp.ones((1, d), rows.dtype), ((0, 0), (0, d_pad)))
     _, bp, dp = r.shape
 
@@ -108,7 +118,7 @@ def bregman_refine_batch(
         grid=(q, bp // bb, dp // bd),
         in_specs=[
             pl.BlockSpec((1, bb, bd), lambda qi, i, j: (qi, i, j)),
-            pl.BlockSpec((1, bd), lambda qi, i, j: (qi, j)),
+            pl.BlockSpec((1, 1, bd), lambda qi, i, j: (qi, 0, j)),
             pl.BlockSpec((1, bd), lambda qi, i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, bb, 1), lambda qi, i, j: (qi, i, 0)),
@@ -130,14 +140,13 @@ def _make_quant_batch_kernel(phi, positive: bool):
         # int8 codes; the affine decode (+ the domain clamp shared with
         # core/quantize.dequantize_rows) happens on-chip per tile.
         x = codes_ref[0].astype(jnp.float32)       # (bb, bd)
-        x = x * scale_ref[0][:, None] + zp_ref[0][:, None]
+        x = x * scale_ref[0] + zp_ref[0]           # (bb, 1) row decode
         if positive:
             x = jnp.maximum(x, DOMAIN_EPS)
-        grad = grad_ref[...]                       # (1, bd)
+        grad = grad_ref[0]                         # (1, bd)
         mask = mask_ref[...]                       # (1, bd)
         fx = jnp.sum(phi(x) * mask, axis=-1, keepdims=True)          # VPU
-        cross = jnp.dot(x, grad.T, preferred_element_type=jnp.float32)
-        acc_ref[0] += fx - cross                   # (bb, 1)
+        acc_ref[0] += fx - _cross(x, grad)         # (bb, 1)
 
     return kernel
 
@@ -175,9 +184,11 @@ def bregman_refine_batch_quant(
     b_pad, d_pad = -b % bb, -d % bd
 
     r = jnp.pad(codes, ((0, 0), (0, b_pad), (0, d_pad)))
-    s = jnp.pad(scale, ((0, 0), (0, b_pad)))
-    z = jnp.pad(zp, ((0, 0), (0, b_pad)), constant_values=1.0)
-    g = jnp.pad(grad, ((0, 0), (0, d_pad)))
+    # Decode scalars ride as (bb, 1) columns and grad as a (1, bd) row of a
+    # unit middle axis, so every block obeys the (8, 128) tiling rule.
+    s = jnp.pad(scale, ((0, 0), (0, b_pad)))[:, :, None]
+    z = jnp.pad(zp, ((0, 0), (0, b_pad)), constant_values=1.0)[:, :, None]
+    g = jnp.pad(grad, ((0, 0), (0, d_pad)))[:, None, :]
     mask = jnp.pad(jnp.ones((1, d), jnp.float32), ((0, 0), (0, d_pad)))
     _, bp, dp = r.shape
 
@@ -186,9 +197,9 @@ def bregman_refine_batch_quant(
         grid=(q, bp // bb, dp // bd),
         in_specs=[
             pl.BlockSpec((1, bb, bd), lambda qi, i, j: (qi, i, j)),
-            pl.BlockSpec((1, bb), lambda qi, i, j: (qi, i)),
-            pl.BlockSpec((1, bb), lambda qi, i, j: (qi, i)),
-            pl.BlockSpec((1, bd), lambda qi, i, j: (qi, j)),
+            pl.BlockSpec((1, bb, 1), lambda qi, i, j: (qi, i, 0)),
+            pl.BlockSpec((1, bb, 1), lambda qi, i, j: (qi, i, 0)),
+            pl.BlockSpec((1, 1, bd), lambda qi, i, j: (qi, 0, j)),
             pl.BlockSpec((1, bd), lambda qi, i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, bb, 1), lambda qi, i, j: (qi, i, 0)),

@@ -19,7 +19,7 @@ the prune scan gets them as a byproduct (core/search uses them for the
 ``tau_admit`` telemetry: the tightest upper bound among admitted rows).
 
 The UB part is a (bn, M) x (M, bq) matmul with a fused rank-1 bias on the
-MXU; the admit part is the static-M broadcast/OR-accumulate loop of
+MXU; the admit part is the per-subspace OR-accumulate loop of
 ``bregman_prune.py`` (the (bn, M, q) lower-bound tensor never exists).
 The int8 variant streams BOTH table pairs as codes (1 byte/entry) with
 four decode scalars per row each, and keeps the Cauchy contraction
@@ -40,7 +40,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bregman_prune import _PAD_AMIN
+from repro.core.bounds import F32_PRECISION
+
+from .bregman_prune import _PAD_AMIN, admit_tile
 
 
 def _make_kernel(m_real: int):
@@ -50,22 +52,12 @@ def _make_kernel(m_real: int):
         alpha = alpha_ref[...]              # (bn, Mp)
         sg = sg_ref[...]
         rowsum = jnp.sum(alpha, axis=-1, keepdims=True)          # (bn, 1)
-        cauchy = jnp.dot(sg, sd, preferred_element_type=jnp.float32)  # MXU
+        cauchy = jnp.dot(sg, sd, precision=F32_PRECISION,
+                         preferred_element_type=jnp.float32)  # MXU
         ub_ref[...] = (rowsum + qsum_ref[...] + cauchy).astype(ub_ref.dtype)
 
-        amin = amin_ref[...]                # (bn, Mp)
-        gmax = gmax_ref[...]
-        qc = qc_ref[...]                    # (Mp, bq)
-        qb = qb_ref[...]
-        hit = None
-        # Static loop over the REAL subspaces only: padded lanes carry
-        # zeros, which would otherwise admit everything (0 <= 0).
-        for i in range(m_real):
-            lb = (amin[:, i:i + 1] + qc[i:i + 1, :]
-                  - gmax[:, i:i + 1] * sd[i:i + 1, :])           # (bn, bq)
-            h = lb <= qb[i:i + 1, :]
-            hit = h if hit is None else (hit | h)
-        admit_ref[...] = hit.astype(admit_ref.dtype)
+        admit_ref[...] = admit_tile(m_real, amin_ref[...], gmax_ref[...],
+                                    qc_ref, sd_ref, qb_ref)
 
     return kernel
 
@@ -83,24 +75,16 @@ def _make_quant_kernel(m_real: int):
         # Per-row affine factored out of both reductions (bregman_ub.py):
         # the code matmul stays a clean int8-upcast MXU contraction.
         rowsum = a_s * jnp.sum(aq, axis=-1, keepdims=True) + m_real * a_z
-        cauchy = (g_s * jnp.dot(sgq, sd, preferred_element_type=jnp.float32)
+        cauchy = (g_s * jnp.dot(sgq, sd, precision=F32_PRECISION,
+                                preferred_element_type=jnp.float32)
                   + g_z * sdsum_ref[...])                # (bn, bq)
         ub_ref[...] = (rowsum + qsum_ref[...] + cauchy).astype(ub_ref.dtype)
 
-        am_s, am_z = ams_ref[...], amz_ref[...]
-        gm_s, gm_z = gms_ref[...], gmz_ref[...]
-        qc = qc_ref[...]
-        qb = qb_ref[...]
-        hit = None
-        for i in range(m_real):
-            # Fused per-column affine decode of the corner codes
-            # (directed-rounded at encode, so the bound is conservative).
-            amin = amq_ref[:, i:i + 1].astype(jnp.float32) * am_s + am_z
-            gmax = gmq_ref[:, i:i + 1].astype(jnp.float32) * gm_s + gm_z
-            lb = amin + qc[i:i + 1, :] - gmax * sd[i:i + 1, :]
-            h = lb <= qb[i:i + 1, :]
-            hit = h if hit is None else (hit | h)
-        admit_ref[...] = hit.astype(admit_ref.dtype)
+        # Fused per-row affine decode of the corner codes (directed-rounded
+        # at encode, so the decoded bound is conservative).
+        amin = amq_ref[...].astype(jnp.float32) * ams_ref[...] + amz_ref[...]
+        gmax = gmq_ref[...].astype(jnp.float32) * gms_ref[...] + gmz_ref[...]
+        admit_ref[...] = admit_tile(m_real, amin, gmax, qc_ref, sd_ref, qb_ref)
 
     return kernel
 

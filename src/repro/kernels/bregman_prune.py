@@ -7,18 +7,18 @@ mask in one VMEM-resident pass:
     lb[n, i, j] = amin[n, i] + qconst[j, i] - gmax[n, i] * sqrt_delta[j, i]
     admit[n, j] = any_i ( lb[n, i, j] <= qb[j, i] )
 
-The (bn, M, q) lower-bound tensor never exists: the subspace axis is a
-static in-kernel loop (M is a few dozen — paper Table 4), each iteration an
-outer broadcast of a (bn, 1) point column against a (1, bq) query row with
-an OR-accumulate, so the only tile that leaves the kernel is the
+The (bn, M, q) lower-bound tensor never exists: the subspace axis is an
+in-kernel ``fori_loop`` (M is a few dozen — paper Table 4), each iteration
+an outer broadcast of a (bn, 1) point column against a (1, bq) query row
+with an OR-accumulate, so the only tile that leaves the kernel is the
 (bn, bq) int32 mask the streaming compaction consumes
 (core/search._stream_prune_compact).
 
 The quantized variant streams int8 corner CODES plus four per-row decode
-scalars and dequantizes per column on-chip — the corner codes were
+scalars and dequantizes the tile on-chip — the corner codes were
 directed-rounded at encode (core/quantize.py), so the decoded bound is
 conservative with no slack term.  Query operands arrive TRANSPOSED,
-(M, q), so the per-subspace slice is a cheap sublane read.
+(M, q), so the per-subspace row is a dynamic sublane read.
 """
 
 from __future__ import annotations
@@ -30,22 +30,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def admit_tile(m_real: int, amin, gmax, qc_ref, sd_ref, qb_ref):
+    """(bn, bq) int32 OR over the real subspaces of ``lb_i <= qb_i``.
+
+    ``amin``/``gmax`` are decoded (bn, Mp) point tiles; the query refs are
+    (Mp, bq).  A ``fori_loop`` keeps one subspace's (bn, bq) tile live at a
+    time; a statically unrolled loop kept every (bn, 1) column live and ran
+    out of VMEM at the paper's M (37 for Deep, 50 for Fonts).  Column i is
+    read by a lane-masked max (exact: only lane i survives), the query row
+    by a dynamic sublane slice.  Padded lanes (i >= m_real) carry zeros,
+    which would admit everything (0 <= 0), so the loop stops at m_real.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, amin.shape, 1)
+
+    def body(i, hit):
+        on = lane == i
+        a = jnp.max(jnp.where(on, amin, -jnp.inf), axis=1, keepdims=True)
+        g = jnp.max(jnp.where(on, gmax, -jnp.inf), axis=1, keepdims=True)
+        lb = a + qc_ref[pl.ds(i, 1), :] - g * sd_ref[pl.ds(i, 1), :]
+        return hit | (lb <= qb_ref[pl.ds(i, 1), :]).astype(jnp.int32)
+
+    init = jnp.zeros((amin.shape[0], qc_ref.shape[1]), jnp.int32)
+    return jax.lax.fori_loop(0, m_real, body, init)
+
+
 def _make_kernel(m_real: int):
     def kernel(amin_ref, gmax_ref, qc_ref, sd_ref, qb_ref, out_ref):
-        amin = amin_ref[...]                # (bn, Mp)
-        gmax = gmax_ref[...]
-        qc = qc_ref[...]                    # (Mp, bq) transposed query operands
-        sd = sd_ref[...]
-        qb = qb_ref[...]
-        hit = None
-        # Static loop over the REAL subspaces only: padded lanes carry
-        # zeros, which would otherwise admit everything (0 <= 0).
-        for i in range(m_real):
-            lb = (amin[:, i:i + 1] + qc[i:i + 1, :]
-                  - gmax[:, i:i + 1] * sd[i:i + 1, :])        # (bn, bq)
-            h = lb <= qb[i:i + 1, :]
-            hit = h if hit is None else (hit | h)
-        out_ref[...] = hit.astype(out_ref.dtype)
+        out_ref[...] = admit_tile(m_real, amin_ref[...], gmax_ref[...],
+                                  qc_ref, sd_ref, qb_ref)
 
     return kernel
 
@@ -53,21 +65,11 @@ def _make_kernel(m_real: int):
 def _make_quant_kernel(m_real: int):
     def kernel(amq_ref, gmq_ref, as_ref, az_ref, gs_ref, gz_ref,
                qc_ref, sd_ref, qb_ref, out_ref):
-        a_s, a_z = as_ref[...], az_ref[...]          # (bn, 1) row decode
-        g_s, g_z = gs_ref[...], gz_ref[...]
-        qc = qc_ref[...]                             # (Mp, bq)
-        sd = sd_ref[...]
-        qb = qb_ref[...]
-        hit = None
-        for i in range(m_real):
-            # Fused per-column affine decode: the HBM stream is int8 codes
-            # plus four f32 scalars per row, never a fp32 corner table.
-            amin = amq_ref[:, i:i + 1].astype(jnp.float32) * a_s + a_z
-            gmax = gmq_ref[:, i:i + 1].astype(jnp.float32) * g_s + g_z
-            lb = amin + qc[i:i + 1, :] - gmax * sd[i:i + 1, :]
-            h = lb <= qb[i:i + 1, :]
-            hit = h if hit is None else (hit | h)
-        out_ref[...] = hit.astype(out_ref.dtype)
+        # Fused per-row affine decode: the HBM stream is int8 codes plus
+        # four f32 scalars per row, never a fp32 corner table.
+        amin = amq_ref[...].astype(jnp.float32) * as_ref[...] + az_ref[...]
+        gmax = gmq_ref[...].astype(jnp.float32) * gs_ref[...] + gz_ref[...]
+        out_ref[...] = admit_tile(m_real, amin, gmax, qc_ref, sd_ref, qb_ref)
 
     return kernel
 

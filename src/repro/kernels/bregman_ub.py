@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.bounds import F32_PRECISION
+
 
 def _kernel(alpha_ref, sg_ref, qsum_ref, sd_ref, out_ref):
     alpha = alpha_ref[...]              # (bn, M)
@@ -28,7 +30,8 @@ def _kernel(alpha_ref, sg_ref, qsum_ref, sd_ref, out_ref):
     qsum = qsum_ref[...]                # (1, bq)
     sd = sd_ref[...]                    # (M, bq)
     rowsum = jnp.sum(alpha, axis=-1, keepdims=True)          # (bn, 1)
-    cauchy = jnp.dot(sg, sd, preferred_element_type=jnp.float32)  # MXU
+    cauchy = jnp.dot(sg, sd, precision=F32_PRECISION,
+                         preferred_element_type=jnp.float32)  # MXU
     out_ref[...] = (rowsum + qsum + cauchy).astype(out_ref.dtype)
 
 
@@ -85,7 +88,7 @@ def _make_quant_kernel(m_real: int):
         # Per-row affine factored out of both reductions: the HBM stream is
         # int8 codes + four f32 scalars per row, not two (M,) f32 tables.
         rowsum = a_s * jnp.sum(aq, axis=-1, keepdims=True) + m_real * a_z
-        cauchy = (g_s * jnp.dot(sgq, sd_ref[...],
+        cauchy = (g_s * jnp.dot(sgq, sd_ref[...], precision=F32_PRECISION,
                                 preferred_element_type=jnp.float32)
                   + g_z * sdsum_ref[...])             # (bn, bq)
         out_ref[...] = (rowsum + qsum_ref[...] + cauchy).astype(out_ref.dtype)

@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.bounds import F32_PRECISION
 from repro.core.bregman import get_family
 from repro.core import quantize as qz
 
@@ -20,14 +21,14 @@ def bregman_ub_totals(alpha: Array, sqrt_gamma: Array, qconst: Array,
                       sqrt_delta: Array) -> Array:
     """Total UB per point for a single query.  (n, M),(n, M),(M,),(M,)->(n,)."""
     return (jnp.sum(alpha, -1) + jnp.sum(qconst, -1)
-            + sqrt_gamma @ sqrt_delta)
+            + jnp.dot(sqrt_gamma, sqrt_delta, precision=F32_PRECISION))
 
 
 def bregman_ub_matrix(alpha: Array, sqrt_gamma: Array, qconst: Array,
                       sqrt_delta: Array) -> Array:
     """UB totals for a query batch.  (n,M),(n,M),(q,M),(q,M) -> (n,q)."""
     return (jnp.sum(alpha, -1)[:, None] + jnp.sum(qconst, -1)[None, :]
-            + sqrt_gamma @ sqrt_delta.T)
+            + jnp.dot(sqrt_gamma, sqrt_delta.T, precision=F32_PRECISION))
 
 
 def bregman_ub_matrix_quant(alpha_q: Array, alpha_scale: Array,
@@ -47,7 +48,9 @@ def bregman_ub_matrix_quant(alpha_q: Array, alpha_scale: Array,
     arow = alpha_scale * jnp.sum(alpha_q.astype(jnp.float32), -1) + m * alpha_zp
     qsum = jnp.sum(qconst, -1)                       # (q,)
     sdsum = jnp.sum(sqrt_delta, -1)                  # (q,)
-    cauchy = (sg_scale[:, None] * (sg_q.astype(jnp.float32) @ sqrt_delta.T)
+    cauchy = (sg_scale[:, None] * jnp.dot(sg_q.astype(jnp.float32),
+                                          sqrt_delta.T,
+                                          precision=F32_PRECISION)
               + sg_zp[:, None] * sdsum[None, :])
     return arow[:, None] + qsum[None, :] + cauchy
 
@@ -129,7 +132,7 @@ def bregman_refine(rows: Array, grad: Array, c_y: Array, family: str) -> Array:
     """Exact D_f for selected rows.  (b,d),(d,),() -> (b,)."""
     fam = get_family(family)
     fx = jnp.sum(fam.phi(rows), axis=-1)
-    return fx - rows @ grad + c_y
+    return fx - jnp.dot(rows, grad, precision=F32_PRECISION) + c_y
 
 
 def bregman_refine_batch(rows: Array, grad: Array, c_y: Array,
@@ -137,7 +140,7 @@ def bregman_refine_batch(rows: Array, grad: Array, c_y: Array,
     """Exact D_f per query's candidate rows.  (q,b,d),(q,d),(q,) -> (q,b)."""
     fam = get_family(family)
     fx = jnp.sum(fam.phi(rows), axis=-1)                  # (q, b)
-    cross = jnp.einsum("qbd,qd->qb", rows, grad)
+    cross = jnp.einsum("qbd,qd->qb", rows, grad, precision=F32_PRECISION)
     return fx - cross + c_y[:, None]
 
 

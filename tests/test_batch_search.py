@@ -250,3 +250,39 @@ def test_refine_batch_dispatch_rejects_bad_rank():
     with pytest.raises(ValueError, match="bregman_refine_batch"):
         ops.bregman_refine_batch(jnp.zeros((4, 8)), jnp.zeros((4, 8)),
                                  jnp.zeros((4,)), "squared_euclidean")
+
+
+@pytest.mark.parametrize("storage", ["f32", "int8"])
+def test_refine_in_query_chunks_matches_one_gather(monkeypatch, storage):
+    """A candidate gather above REFINE_GATHER_BYTES is refined in query
+    chunks (lax.map); the chunked result has the one-gather result's ids,
+    and its distances up to the reduction order of a smaller batch."""
+    data, queries, _ = _dataset("exponential", n=300, q=5, seed=4)
+    index = build_index(data, "exponential", m=4, num_clusters=16, seed=0,
+                        quantize=storage == "int8")
+    qs = search._query_struct(index, jnp.asarray(queries))
+    rng = np.random.default_rng(0)
+    sel = jnp.asarray(rng.integers(0, index.n, (5, 64)), jnp.int32)
+    valid = jnp.asarray(rng.random((5, 64)) < 0.9)
+    whole = search._refine_batch(index, qs, sel, valid, 7)
+    row_bytes = 64 * index.d * index.data.dtype.itemsize
+    monkeypatch.setattr(search, "REFINE_GATHER_BYTES", 2 * row_bytes)
+    chunked = search._refine_batch(index, qs, sel, valid, 7)
+    np.testing.assert_array_equal(np.asarray(chunked[0]),
+                                  np.asarray(whole[0]))
+    np.testing.assert_allclose(np.asarray(chunked[1]),
+                               np.asarray(whole[1]), rtol=1e-6)
+
+
+def test_build_index_logs_phase_seconds(caplog):
+    """build_index reports its cost-model / PCCP / k-means wall times on
+    one log record (chip_smoke.py reads them from ``build_seconds``)."""
+    data, _, _ = _dataset("squared_euclidean", n=200, seed=5)
+    with caplog.at_level(logging.INFO, logger="repro.core.index"):
+        build_index(data, "squared_euclidean", num_clusters=8, seed=0)
+    recs = [r for r in caplog.records if hasattr(r, "build_seconds")]
+    assert len(recs) == 1
+    s = recs[0].build_seconds
+    assert set(s) == {"cost_model", "pccp", "kmeans", "total"}
+    assert all(v >= 0.0 for v in s.values())
+    assert s["total"] >= s["cost_model"] + s["pccp"] + s["kmeans"]

@@ -96,6 +96,13 @@ def _prune_inputs(rng, n, m, q):
 def test_prune_kernel_shapes(n, m, q):
     rng = np.random.default_rng(0)
     amin, gmax, qc, sd, qb = _prune_inputs(rng, n, m, q)
+    # Admission ORs over m subspaces, so N(0, 1) bounds admit every pair at
+    # m = 50.  Set each (query, subspace) bound at the lower-bound quantile
+    # that admits about half the points overall: p_i = 1 - 0.5 ** (1 / m).
+    lb = (np.asarray(amin)[:, :, None] + np.asarray(qc).T[None]
+          - np.asarray(gmax)[:, :, None] * np.asarray(sd).T[None])
+    qb = jnp.asarray(np.quantile(lb, 1.0 - 0.5 ** (1.0 / m), axis=0).T,
+                     jnp.float32)
     got = bregman_prune_mask(amin, gmax, qc, sd, qb,
                              block_n=32, block_q=4, interpret=True)
     want = ref.bregman_prune_mask(amin, gmax, qc, sd, qb)

@@ -298,3 +298,26 @@ def test_vector_datasets_match_table4():
             assert data.min() > 0
         q = data_pipe.make_queries(spec, num=5, scale=0.001)
         assert q.shape == (5, spec.d)
+
+
+def test_vector_datasets_same_in_every_process():
+    """A seeded dataset is the same array in every process: the per-name
+    seed must not come from str hash(), which PYTHONHASHSEED salts."""
+    import hashlib
+    import subprocess
+    import sys
+    code = ("import hashlib; from repro.data import pipeline as p; "
+            "x = p.make_vectors(p.PAPER_DATASETS['deep'], scale=1e-4); "
+            "print(hashlib.sha256(x.tobytes()).hexdigest())")
+    here = data_pipe.make_vectors(data_pipe.PAPER_DATASETS["deep"],
+                                  scale=1e-4)
+    digests = set()
+    for salt in ("1", "2"):
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = dict(os.environ, PYTHONHASHSEED=salt, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.path.abspath(src))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, check=True,
+                             timeout=300)
+        digests.add(out.stdout.split()[-1])
+    assert digests == {hashlib.sha256(here.tobytes()).hexdigest()}

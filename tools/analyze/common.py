@@ -215,7 +215,7 @@ class Project:
         """Alias-expanded dotted name of an expression, if nameable.
 
         ``np.asarray`` -> ``numpy.asarray``; ``shd.shard_map`` ->
-        ``repro.dist.compat.shard_map``; plain names resolve through
+        ``repro.dist.sharding.shard_map``; plain names resolve through
         from-imports (``partial`` -> ``functools.partial``).
         """
         dotted = dotted_name(node)

@@ -49,7 +49,7 @@ _WRAPPERS = {
     "jax.lax.cond": (1, 2), "jax.lax.switch": (1,),
 }
 # wrappers matched on the final attribute regardless of module prefix
-# (compat shims re-export shard_map; pallas is imported as ``pl``).
+# (dist/sharding wraps shard_map; pallas is imported as ``pl``).
 _WRAPPER_ATTRS = {"shard_map": (0,), "pallas_call": (0,)}
 
 _COERCIONS = {"float", "int", "bool"}
