@@ -1,24 +1,13 @@
 """Markdown delta table between two BENCH_*.json artifacts.
 
     python benchmarks/bench_delta.py PREV.json CURRENT.json
-    python benchmarks/bench_delta.py --ratchet PREV.json CURRENT.json
 
 Reads the ``benchmarks.run --json`` payloads, joins rows on
 ``(bench, name)``, and prints a GitHub-flavored markdown table of
 us/call and qps deltas — CI appends it to the job summary so perf
 regressions are visible at review time without downloading artifacts.
-In the default (table) mode the script never fails the job: any
-malformed input degrades to a note (the delta is advisory; the
-artifacts remain the source of truth).
-
-``--ratchet`` is the BLOCKING mode: it compares only the
-``kernel_roofline`` rows' ``derived.roofline_fraction`` and exits 1 when
-a kernel's achieved fraction of the roofline dropped by more than
-``ROOFLINE_DROP_TOL`` relative — the regression gate the roofline
-summary was promoted into (ROADMAP "Roofline follow-ups").  Missing
-artifacts still exit 0 (first push of a branch has no baseline); a
-fetched baseline that parses but lost a kernel row fails, so rows
-cannot silently disappear from the gate.
+The script never fails the job: any malformed input degrades to a note
+(the delta is advisory; the artifacts remain the source of truth).
 """
 
 from __future__ import annotations
@@ -29,14 +18,6 @@ import sys
 # us/call swings below this are timer noise on shared CI runners; the
 # table marks larger ones so reviewers scan only the meaningful lines.
 NOISE_PCT = 10.0
-
-# Relative drop in derived.roofline_fraction that fails the ratchet.
-# Wide on purpose: shared CI runners jitter the achieved bandwidth run
-# to run, and the gate exists to catch structural regressions (a kernel
-# falling off its fused path), not single-digit noise.
-ROOFLINE_DROP_TOL = 0.30
-
-ROOFLINE_BENCH = "kernel_roofline"
 
 
 def _rows(path):
@@ -53,77 +34,9 @@ def _fmt_pct(pct):
     return f"{pct:+.1f}%{mark}"
 
 
-def _roofline_fractions(rows):
-    out = {}
-    for (bench, name), r in rows.items():
-        if bench != ROOFLINE_BENCH:
-            continue
-        frac = (r.get("derived") or {}).get("roofline_fraction")
-        if isinstance(frac, (int, float)):
-            out[name] = float(frac)
-    return out
-
-
-def ratchet(prev_path, cur_path) -> int:
-    """Blocking roofline gate; returns the process exit code."""
-    try:
-        _, prev = _rows(prev_path)
-    except (OSError, ValueError, KeyError) as e:
-        # No baseline (first push of a branch / expired artifact) is not
-        # a regression — the CURRENT artifact becomes the next baseline.
-        print(f"roofline ratchet: no usable baseline ({e}); passing")
-        return 0
-    try:
-        _, cur = _rows(cur_path)
-    except (OSError, ValueError, KeyError) as e:
-        print(f"roofline ratchet: current artifact unreadable: {e}")
-        return 1
-    base = _roofline_fractions(prev)
-    now = _roofline_fractions(cur)
-    if not base:
-        print("roofline ratchet: baseline has no kernel_roofline rows; "
-              "passing")
-        return 0
-
-    failures = []
-    print("## Roofline ratchet")
-    print()
-    print("| kernel | baseline | current | rel Δ | status |")
-    print("|---|---:|---:|---:|---|")
-    for name in sorted(base):
-        if name not in now:
-            failures.append(f"{name}: roofline row vanished from the "
-                            "current run")
-            print(f"| {name} | {base[name]:.3f} | — | — | MISSING |")
-            continue
-        rel = (now[name] - base[name]) / base[name] if base[name] else 0.0
-        ok = rel >= -ROOFLINE_DROP_TOL
-        status = "ok" if ok else "REGRESSED"
-        if not ok:
-            failures.append(
-                f"{name}: roofline_fraction {base[name]:.3f} -> "
-                f"{now[name]:.3f} ({rel:+.1%}, tolerance "
-                f"-{ROOFLINE_DROP_TOL:.0%})")
-        print(f"| {name} | {base[name]:.3f} | {now[name]:.3f} "
-              f"| {rel:+.1%} | {status} |")
-    for name in sorted(set(now) - set(base)):
-        print(f"| {name} | — | {now[name]:.3f} | — | new |")
-    print()
-    if failures:
-        print("roofline ratchet FAILED:")
-        for f in failures:
-            print(f"  - {f}")
-        return 1
-    print(f"roofline ratchet OK ({len(base)} kernels, tolerance "
-          f"-{ROOFLINE_DROP_TOL:.0%} relative)")
-    return 0
-
-
 def main(argv) -> int:
-    if len(argv) == 4 and argv[1] == "--ratchet":
-        return ratchet(argv[2], argv[3])
     if len(argv) != 3:
-        print("usage: bench_delta.py [--ratchet] PREV.json CURRENT.json",
+        print("usage: bench_delta.py PREV.json CURRENT.json",
               file=sys.stderr)
         return 0                       # advisory: never fail the job
     try:

@@ -5,9 +5,7 @@
 
 Prints ``bench,name,us_per_call,derived`` CSV rows; ``--json`` also writes
 the rows (plus failures and wall time) to a machine-readable file — CI
-uploads it as the ``BENCH_*.json`` artifact on every push.  The roofline
-table (deliverable g) reads the dry-run JSON instead:
-``benchmarks/roofline.py``.
+uploads it as the ``BENCH_*.json`` artifact on every push.
 """
 
 from __future__ import annotations
@@ -36,7 +34,6 @@ MODULES = [
     "bench_dist_knn",        # shard-count scaling on the devices present
     "bench_retrieval",       # retrieval-service overhead (chaos: --chaos)
     "bench_kernels",         # kernel micro-benches
-    "bench_kernel_roofline",  # fused vs unfused kernel HLO roofline terms
     "bench_recall_frontier",  # calibrated approx tier: recall-vs-QPS + ppl
     "bench_tiered",          # out-of-core tier: fetched bytes + wall ratio
 ]

@@ -877,12 +877,14 @@ def _refine_dists(index: BallForest, grad: Array, c_y: Array, sel: Array):
     """
     from repro.kernels import ops as kernel_ops
     if index.storage == "int8":
-        codes = jnp.take(index.data, sel, axis=0)       # (q, budget, d) int8
-        scale = jnp.take(index.data_scale, sel)         # (q, budget)
-        zp = jnp.take(index.data_zp, sel)
+        with jax.named_scope("gather"):
+            codes = jnp.take(index.data, sel, axis=0)   # (q, budget, d) int8
+            scale = jnp.take(index.data_scale, sel)     # (q, budget)
+            zp = jnp.take(index.data_zp, sel)
         return kernel_ops.bregman_refine_batch_quant(
             codes, scale, zp, grad, c_y, index.family_name)
-    rows = jnp.take(index.data, sel, axis=0)            # (q, budget, d)
+    with jax.named_scope("gather"):
+        rows = jnp.take(index.data, sel, axis=0)        # (q, budget, d)
     return kernel_ops.bregman_refine_batch(rows, grad, c_y,
                                            index.family_name)
 
@@ -936,41 +938,46 @@ def _knn_search_batch_core(index: BallForest, ys: Array, k: int, budget: int,
                          "top-k needs at least k slots)")
     if ys.ndim != 2:
         raise ValueError(f"expected (q, d) queries, got {ys.shape}")
-    qs = _query_struct(index, ys)                       # all fields (q, ...)
-
+    # Each phase runs under a named scope (bp.filter, bp.prune, bp.refine):
+    # the scope rides every HLO instruction's op_name metadata, so a
+    # profiler trace attributes device time to phases.  Compile-time only.
     # ---- phase 1+2: one fused filter matmul + streaming k-selection ----
     # The k-th row's tuple sets qb; the full top-k indices feed the int8
     # tier's bound slack (max rounding error over the rows that could have
     # determined the k-th UB).
-    _, idx = _batch_filter_topk(index, qs, k, block_rows)
-    kth = idx[:, -1]                                    # (q,)
-    kth_tuple = _tuple_rows(index, kth)
-    sqrt_term = kth_tuple["sqrt_gamma"] * qs["sqrt_delta"]       # (q, M)
-    qb = (bounds.ub_components(kth_tuple, qs)           # (q, M) Alg. 4
-          + _qb_slack(index, idx, qs["sqrt_delta"]))
+    with jax.named_scope("bp.filter"):
+        qs = _query_struct(index, ys)                   # all fields (q, ...)
+        _, idx = _batch_filter_topk(index, qs, k, block_rows)
+        kth = idx[:, -1]                                # (q,)
+        kth_tuple = _tuple_rows(index, kth)
+        sqrt_term = kth_tuple["sqrt_gamma"] * qs["sqrt_delta"]   # (q, M)
+        qb = (bounds.ub_components(kth_tuple, qs)       # (q, M) Alg. 4
+              + _qb_slack(index, idx, qs["sqrt_delta"]))
 
-    if p_guarantee is not None:                         # §8 shrink, batched
-        kappa_i = qb - sqrt_term
-        c = _cdf_shrink(index.beta_samples, jnp.sum(sqrt_term, -1),
-                        jnp.sum(kappa_i, -1), p_guarantee)
-        qb = kappa_i + c[:, None] * sqrt_term
+        if p_guarantee is not None:                     # §8 shrink, batched
+            kappa_i = qb - sqrt_term
+            c = _cdf_shrink(index.beta_samples, jnp.sum(sqrt_term, -1),
+                            jnp.sum(kappa_i, -1), p_guarantee)
+            qb = kappa_i + c[:, None] * sqrt_term
 
     # ---- phase 3+4: streaming prune + compact (block-skip from envelopes),
     # then one batched refine ----
-    if streaming:
-        (sel, valid, num_candidates, env_admitted, blocks_run,
-         tau) = _stream_prune_compact(index, qs, qb, budget, block_rows,
-                                      fused=fused,
-                                      env_block_rows=env_block_rows,
-                                      with_tau=with_stats and fused)
-    else:
-        # Reference path: materialized (n, q) mask + (q, n) cumsum.
-        mask = _candidate_mask_batch(index, qs, qb, block_rows)
-        sel, valid, num_candidates = _compact_candidates(mask, budget)
-        env_admitted = jnp.zeros((ys.shape[0],), jnp.int32)
-        blocks_run = jnp.zeros((), jnp.int32)
-        tau = jnp.full((ys.shape[0],), POS_BIG, jnp.float32)
-    ids, dists = _refine_batch(index, qs, sel, valid, k)
+    with jax.named_scope("bp.prune"):
+        if streaming:
+            (sel, valid, num_candidates, env_admitted, blocks_run,
+             tau) = _stream_prune_compact(index, qs, qb, budget, block_rows,
+                                          fused=fused,
+                                          env_block_rows=env_block_rows,
+                                          with_tau=with_stats and fused)
+        else:
+            # Reference path: materialized (n, q) mask + (q, n) cumsum.
+            mask = _candidate_mask_batch(index, qs, qb, block_rows)
+            sel, valid, num_candidates = _compact_candidates(mask, budget)
+            env_admitted = jnp.zeros((ys.shape[0],), jnp.int32)
+            blocks_run = jnp.zeros((), jnp.int32)
+            tau = jnp.full((ys.shape[0],), POS_BIG, jnp.float32)
+    with jax.named_scope("bp.refine"):
+        ids, dists = _refine_batch(index, qs, sel, valid, k)
     res = SearchResult(ids=ids, dists=dists,
                        exact=num_candidates <= budget,
                        num_candidates=num_candidates)

@@ -187,57 +187,69 @@ def _dist_knn_program(mesh: Mesh, axis: str, family_name: str,
         # come from the program cell, so this IS the local shard's index.
         local = BallForest(family_name, partition, num_clusters,
                            storage=storage, **arrs)
+        # The phases run under the single-host pipeline's scopes
+        # (bp.filter, bp.prune, bp.refine); the k-way merge under bp.merge.
         # ---- local filter + GLOBAL Alg.-4 bound via the k-way exchange ----
-        vals, idx = _batch_filter_topk(local, qs, k, block_rows)
-        tup = _tuple_rows(local, idx)                   # decoded in int8 tier
-        a_k, g_k = tup["alpha"], tup["sqrt_gamma"]      # (q, k, M)
-        vals_g = jax.lax.all_gather(vals, axis, axis=1, tiled=True)
-        a_g = jax.lax.all_gather(a_k, axis, axis=1, tiled=True)
-        g_g = jax.lax.all_gather(g_k, axis, axis=1, tiled=True)
-        if storage == "int8":
-            # Ship each local top-k row's stat scales with its tuple: the
-            # global bound must carry the rounding slack of whichever
-            # shard's rows set the global k-th UB (docs/quantization.md).
-            sa_g = jax.lax.all_gather(
-                jnp.take(local.alpha_scale, idx), axis, axis=1, tiled=True)
-            sg_g = jax.lax.all_gather(
-                jnp.take(local.sg_scale, idx), axis, axis=1, tiled=True)
-        neg, sel = jax.lax.top_k(-vals_g, k)            # global k smallest
-        kth = sel[:, -1:, None]                         # (q, 1, 1)
-        m = a_g.shape[-1]
+        with jax.named_scope("bp.filter"):
+            vals, idx = _batch_filter_topk(local, qs, k, block_rows)
+            tup = _tuple_rows(local, idx)               # decoded in int8 tier
+            a_k, g_k = tup["alpha"], tup["sqrt_gamma"]  # (q, k, M)
+            vals_g = jax.lax.all_gather(vals, axis, axis=1, tiled=True)
+            a_g = jax.lax.all_gather(a_k, axis, axis=1, tiled=True)
+            g_g = jax.lax.all_gather(g_k, axis, axis=1, tiled=True)
+            if storage == "int8":
+                # Ship each local top-k row's stat scales with its tuple:
+                # the global bound must carry the rounding slack of
+                # whichever shard's rows set the global k-th UB
+                # (docs/quantization.md).
+                sa_g = jax.lax.all_gather(
+                    jnp.take(local.alpha_scale, idx), axis, axis=1,
+                    tiled=True)
+                sg_g = jax.lax.all_gather(
+                    jnp.take(local.sg_scale, idx), axis, axis=1, tiled=True)
+            neg, sel = jax.lax.top_k(-vals_g, k)        # global k smallest
+            kth = sel[:, -1:, None]                     # (q, 1, 1)
+            m = a_g.shape[-1]
 
-        def take_kth(t):
-            return jnp.take_along_axis(
-                t, jnp.broadcast_to(kth, kth.shape[:1] + (1, m)), axis=1)[:, 0]
-        kth_tuple = {"alpha": take_kth(a_g), "sqrt_gamma": take_kth(g_g)}
-        qb = bounds.ub_components(kth_tuple, qs)        # (q, M)
-        if storage == "int8":
-            a_s = jnp.max(jnp.take_along_axis(sa_g, sel, axis=1), axis=-1)
-            g_s = jnp.max(jnp.take_along_axis(sg_g, sel, axis=1), axis=-1)
-            qb = qb + ub_slack(a_s, g_s, qs["sqrt_delta"])
-        if approx:                                      # §8 shrink, batched
-            sqrt_term = kth_tuple["sqrt_gamma"] * qs["sqrt_delta"]
-            kappa_i = qb - sqrt_term
-            c = _cdf_shrink(local.beta_samples, jnp.sum(sqrt_term, -1),
-                            jnp.sum(kappa_i, -1), p_guarantee)
-            qb = kappa_i + c[:, None] * sqrt_term
+            def take_kth(t):
+                return jnp.take_along_axis(
+                    t, jnp.broadcast_to(kth, kth.shape[:1] + (1, m)),
+                    axis=1)[:, 0]
+            kth_tuple = {"alpha": take_kth(a_g), "sqrt_gamma": take_kth(g_g)}
+            qb = bounds.ub_components(kth_tuple, qs)    # (q, M)
+            if storage == "int8":
+                a_s = jnp.max(jnp.take_along_axis(sa_g, sel, axis=1),
+                              axis=-1)
+                g_s = jnp.max(jnp.take_along_axis(sg_g, sel, axis=1),
+                              axis=-1)
+                qb = qb + ub_slack(a_s, g_s, qs["sqrt_delta"])
+            if approx:                                  # §8 shrink, batched
+                sqrt_term = kth_tuple["sqrt_gamma"] * qs["sqrt_delta"]
+                kappa_i = qb - sqrt_term
+                c = _cdf_shrink(local.beta_samples, jnp.sum(sqrt_term, -1),
+                                jnp.sum(kappa_i, -1), p_guarantee)
+                qb = kappa_i + c[:, None] * sqrt_term
 
         # ---- local streaming prune + compact + refine (reused phases) ----
         # The replicated envelope tables are GLOBAL; this shard's rows
         # start at axis_index * local_n of the padded global layout.
-        offset = jax.lax.axis_index(axis).astype(jnp.int32) * local.n
-        sel_c, valid, ncand, _, _, _ = _stream_prune_compact(
-            local, qs, qb, budget, block_rows, row_offset=offset)
-        ids, dists = _refine_batch(local, qs, sel_c, valid, k)
+        with jax.named_scope("bp.prune"):
+            offset = jax.lax.axis_index(axis).astype(jnp.int32) * local.n
+            sel_c, valid, ncand, _, _, _ = _stream_prune_compact(
+                local, qs, qb, budget, block_rows, row_offset=offset)
+        with jax.named_scope("bp.refine"):
+            ids, dists = _refine_batch(local, qs, sel_c, valid, k)
 
         # ---- k-way merge + exactness/union-size reductions ----
-        ids_g = jax.lax.all_gather(ids, axis, axis=1, tiled=True)
-        d_g = jax.lax.all_gather(dists, axis, axis=1, tiled=True)
-        negd, pos = jax.lax.top_k(-d_g, k)
-        overflowed = jax.lax.psum((ncand > budget).astype(jnp.int32), axis)
-        return (jnp.take_along_axis(ids_g, pos, axis=1), -negd,
-                overflowed == 0, jax.lax.psum(ncand, axis),
-                jax.lax.pmax(ncand, axis))
+        with jax.named_scope("bp.merge"):
+            ids_g = jax.lax.all_gather(ids, axis, axis=1, tiled=True)
+            d_g = jax.lax.all_gather(dists, axis, axis=1, tiled=True)
+            negd, pos = jax.lax.top_k(-d_g, k)
+            overflowed = jax.lax.psum((ncand > budget).astype(jnp.int32),
+                                      axis)
+            return (jnp.take_along_axis(ids_g, pos, axis=1), -negd,
+                    overflowed == 0, jax.lax.psum(ncand, axis),
+                    jax.lax.pmax(ncand, axis))
 
     arr_specs = {**{f: P(axis) for f in point_fields(storage)},
                  **{f: P() for f in REPLICATED_FIELDS}}

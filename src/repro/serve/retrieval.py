@@ -333,7 +333,13 @@ class RetrievalService:
             "deadline_sheds": 0, "poisoned_rows": 0,
             QUALITY_EXACT: 0, QUALITY_APPROX: 0, QUALITY_PARTIAL: 0,
             QUALITY_SHED: 0,
+            # Microbatches that launched, the requests they carried, and
+            # (float seconds) the requests' summed queue wait and the
+            # microbatches' host time outside launch waits.
+            "microbatches": 0, "microbatch_requests": 0,
+            "queue_s": 0.0, "host_s": 0.0,
         }
+        self._batches = 0           # microbatch ids, one per microbatch
 
     # -- tenants ------------------------------------------------------------
 
@@ -562,6 +568,10 @@ class RetrievalService:
 
         Returns the number of requests resolved this tick.
         """
+        with jax.profiler.TraceAnnotation("svc.step"):
+            return self._step()
+
+    def _step(self) -> int:
         resolved = 0
         now = self.clock.now()
         # Expire queued requests whose deadline already passed — shedding
@@ -667,7 +677,31 @@ class RetrievalService:
                         target_recall) -> int:
         """Run one microbatch; returns how many requests were RESOLVED
         (a deadline shed requeues batchmates whose own deadlines still
-        have slack, so the count can be less than ``len(reqs)``)."""
+        have slack, so the count can be less than ``len(reqs)``).
+
+        The microbatch gets the next id; its ``svc.microbatch`` and
+        ``svc.launch`` spans and its requests' ``meta["batch"]`` carry it.
+        """
+        mb = {"batch": self._batches, "start": None, "launches": []}
+        self._batches += 1
+        rows = sum(r.queries.shape[0] for r in reqs)
+        bucket = next((b for b in self.config.buckets if b >= rows), rows)
+        t0 = self.clock.now()
+        with jax.profiler.TraceAnnotation("svc.microbatch", batch=mb["batch"],
+                                          rows=rows, bucket=bucket):
+            resolved = self._microbatch(tenant, reqs, target_recall, bucket,
+                                        mb)
+        if mb["start"] is not None:
+            waits = sum(x["wait_s"] for x in mb["launches"])
+            self.counters["microbatches"] += 1
+            self.counters["microbatch_requests"] += len(reqs)
+            self.counters["queue_s"] += sum(mb["start"] - r.submitted_at
+                                            for r in reqs)
+            self.counters["host_s"] += self.clock.now() - t0 - waits
+        return resolved
+
+    def _microbatch(self, tenant: Tenant, reqs: list, target_recall,
+                    bucket: int, mb: dict) -> int:
         cfg = self.config
         now = self.clock.now()
         deadline = min(r.deadline for r in reqs)
@@ -688,7 +722,6 @@ class RetrievalService:
         filler = ys[int(np.argmax(ok))]
         ys[~ok] = filler
         q_total = ys.shape[0]
-        bucket = next((b for b in cfg.buckets if b >= q_total), q_total)
         if bucket > q_total:
             ys = np.concatenate(
                 [ys, np.broadcast_to(filler, (bucket - q_total,
@@ -729,8 +762,10 @@ class RetrievalService:
             p, expected = breg_cal.resolve_p_guarantee(snapshot,
                                                        target_recall)
 
+        # One list of launch records, shared by the microbatch's requests.
         meta: dict = {"bucket": bucket, "attempts": 0, "tier_path": [],
-                      "p_guarantee": p}
+                      "p_guarantee": p, "batch": mb["batch"],
+                      "launches": mb["launches"]}
         if expected is not None:
             meta["expected_recall"] = expected
         if cfg.record_snapshots:
@@ -746,7 +781,8 @@ class RetrievalService:
             meta["attempts"] += 1
             try:
                 res, used_approx, budget = self._run_tier(
-                    tenant, snapshot, ys, k, tier, p, deadline)
+                    tenant, snapshot, ys, k, tier, p, deadline, mb,
+                    q_total)
                 meta["budget"] = budget
                 break
             except Exception as e:  # noqa: BLE001 — containment layer
@@ -764,6 +800,13 @@ class RetrievalService:
                     min(back, max(0.0, deadline - self.clock.now())))
 
         finished = self.clock.now()
+
+        def req_meta(r: _Request) -> dict:
+            out = dict(meta)
+            if mb["start"] is not None:     # queued until the first launch
+                out["queue_s"] = mb["start"] - r.submitted_at
+            return out
+
         if res is None:
             reason = "launch_failed" if error else "deadline"
             if not error:
@@ -785,25 +828,26 @@ class RetrievalService:
                 self._resolve_shed(r.ticket, r.uid, r.tenant,
                                    r.queries.shape[0], r.k, r.submitted_at,
                                    finished, reason=reason, error=error,
-                                   retry_after=retry, meta=dict(meta),
+                                   retry_after=retry, meta=req_meta(r),
                                    deadline=r.deadline)
                 resolved += 1
             for r in reversed(requeue):     # back to the head, FIFO order
                 self.queue.appendleft(r)
             return resolved
 
-        ids = np.asarray(res.ids)[:q_total]
-        dists = np.asarray(res.dists)[:q_total]
-        exact = np.asarray(res.exact)[:q_total]
-        row = 0
-        for r in reqs:
-            q = r.queries.shape[0]
-            sl = slice(row, row + q)
-            self._resolve(r, ids[sl].copy(), dists[sl].copy(), exact[sl],
-                          ok[sl], used_approx, finished, dict(meta),
-                          expected_recall=(expected if used_approx
-                                           else None))
-            row += q
+        with jax.profiler.TraceAnnotation("svc.resolve", batch=mb["batch"]):
+            ids = np.asarray(res.ids)[:q_total]
+            dists = np.asarray(res.dists)[:q_total]
+            exact = np.asarray(res.exact)[:q_total]
+            row = 0
+            for r in reqs:
+                q = r.queries.shape[0]
+                sl = slice(row, row + q)
+                self._resolve(r, ids[sl].copy(), dists[sl].copy(), exact[sl],
+                              ok[sl], used_approx, finished, req_meta(r),
+                              expected_recall=(expected if used_approx
+                                               else None))
+                row += q
         return len(reqs)
 
     def _choose_tier(self, tenant: Tenant, remaining: float,
@@ -825,11 +869,13 @@ class RetrievalService:
         return QUALITY_SHED
 
     def _run_tier(self, tenant: Tenant, snapshot, ys, k: int, tier: str,
-                  p: float, deadline: float):
+                  p: float, deadline: float, mb: dict, q: int):
         """Run one ladder tier to completion; returns (result, used_approx,
         budget).  Budget retries inside the exact/approx tiers reuse the
         ``fitted_budget`` machinery but stop when the NEXT launch would
         not fit the remaining deadline — the budget-capped partial path.
+        Each launch is recorded in the microbatch's ``mb``; ``q`` is its
+        real (unpadded) query rows.
         """
         cfg = self.config
         approx = tier == QUALITY_APPROX
@@ -842,7 +888,7 @@ class RetrievalService:
             if tier == QUALITY_PARTIAL:
                 budget = bp.fitted_budget(snapshot, k, 2 * k)
             res = self._launch(
-                tenant, tier,
+                tenant, tier, budget, mb, q,
                 lambda: dist_knn.distributed_knn(
                     tenant.sharded, ys,
                     family=tenant.family_name, k=k, budget=budget,
@@ -857,7 +903,7 @@ class RetrievalService:
         if tier == QUALITY_PARTIAL:
             budget = bp.fitted_budget(snapshot, k, 2 * k)
             res = self._launch(
-                tenant, tier,
+                tenant, tier, budget, mb, q,
                 lambda: bp.knn_search_batch(snapshot, ys, k, budget,
                                             block_rows=tenant.block_rows,
                                             validate=False))
@@ -868,13 +914,13 @@ class RetrievalService:
             b = budget
             if approx:
                 res = self._launch(
-                    tenant, tier,
+                    tenant, tier, b, mb, q,
                     lambda: bp.knn_search_batch_approx(
                         snapshot, ys, k, b, np.float32(p),
                         block_rows=tenant.block_rows, validate=False))
             else:
                 res = self._launch(
-                    tenant, tier,
+                    tenant, tier, b, mb, q,
                     lambda: bp.knn_search_batch(snapshot, ys, k, b,
                                                 block_rows=tenant.block_rows,
                                                 validate=False))
@@ -888,8 +934,14 @@ class RetrievalService:
             budget = bp.fitted_budget(
                 snapshot, k, int(np.asarray(res.num_candidates).max()))
 
-    def _launch(self, tenant: Tenant, tier: str, thunk):
-        """One guarded launch: faults, timing, cost model, breaker."""
+    def _launch(self, tenant: Tenant, tier: str, budget: int, mb: dict,
+                q: int, thunk):
+        """One guarded launch: faults, timing, cost model, breaker.
+
+        A completed launch appends its record to ``mb["launches"]``: tier,
+        budget, real query rows ``q``, the seconds spent dispatching and
+        waiting for the device, and the rows' Theorem-3 union sizes.
+        """
         cfg = self.config
         attempt = self.counters["launches"]
         # A launch is really going out now: if the breaker was open (and
@@ -903,24 +955,40 @@ class RetrievalService:
         # concerned — unattributed stalls would silently erode the
         # "deadline + one launch" guarantee.
         t0 = self.clock.now()
+        if mb["start"] is None:
+            mb["start"] = t0
         extra = 0.0
-        if self.faults is not None:
-            extra = self.faults.before_launch(
-                tenant.name, tier, attempt, tenant_obj=tenant, service=self)
         timed_out = False
-        try:
-            res = thunk()
-            jax.block_until_ready(res)
-        except dist_knn.LaunchTimeout as e:
-            # The launch COMPLETED but blocked past the timeout: use the
-            # result, count the failure (slow shards must trip the
-            # breaker before they wedge the queue).
-            if e.result is None:
-                raise
-            res, timed_out = e.result, True
-        if extra > 0:
-            self.clock.sleep(extra)
+        with jax.profiler.TraceAnnotation("svc.launch", batch=mb["batch"],
+                                          tier=tier, budget=budget,
+                                          attempt=attempt):
+            if self.faults is not None:
+                extra = self.faults.before_launch(
+                    tenant.name, tier, attempt, tenant_obj=tenant,
+                    service=self)
+            try:
+                with jax.profiler.TraceAnnotation("svc.dispatch"):
+                    t1 = self.clock.now()
+                    res = thunk()
+                with jax.profiler.TraceAnnotation("svc.wait"):
+                    t2 = self.clock.now()
+                    jax.block_until_ready(res)
+                    t3 = self.clock.now()
+            except dist_knn.LaunchTimeout as e:
+                # The launch COMPLETED but blocked past the timeout: use
+                # the result, count the failure (slow shards must trip the
+                # breaker before they wedge the queue).
+                if e.result is None:
+                    raise
+                res, timed_out = e.result, True
+                t2 = t3 = self.clock.now()
+            if extra > 0:
+                self.clock.sleep(extra)
         elapsed = self.clock.now() - t0
+        mb["launches"].append({
+            "tier": tier, "budget": int(budget), "q": q,
+            "dispatch_s": t2 - t1, "wait_s": t3 - t2,
+            "num_candidates": np.asarray(res.num_candidates)[:q].tolist()})
         tenant.cost.observe(elapsed)
         self.counters["launches"] += 1
         if self.faults is not None:

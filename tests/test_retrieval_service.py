@@ -395,6 +395,112 @@ def test_quality_shed_is_explicit(index, queries):
 
 
 # ---------------------------------------------------------------------------
+# Telemetry: per-launch records in meta, counters, service spans
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("scenario", ["escalation", "coalesced", "retry",
+                                      "latency", "wall_clock"])
+def test_meta_records_each_launch(index, queries, scenario):
+    """One record per completed launch, shared by the microbatch's
+    requests; budgets ascend to the answer's; the timed parts of the
+    launches fit inside each request's latency."""
+    faults = {"retry": FaultPlan([LaunchError(at_launches=0)], seed=4),
+              "latency": FaultPlan([LatencySpike(SPIKE)], seed=1)}
+    if scenario == "wall_clock":
+        svc = RetrievalService(ServiceConfig(default_deadline_s=60.0))
+        svc.register_tenant("t", index)
+    else:
+        svc, _ = make_service(index, faults=faults.get(scenario))
+    blocks = ([queries[i:i + 1] for i in range(3)] if scenario == "coalesced"
+              else [queries])
+    before = dict(svc.counters)
+    tickets = [svc.submit("t", b, K) for b in blocks]
+    svc.run_until_drained()
+    rs = [t.response for t in tickets]
+    assert all(r.quality == "exact" for r in rs)
+    launches = rs[0].meta["launches"]
+    assert all(r.meta["launches"] is launches for r in rs)
+    assert len({r.meta["batch"] for r in rs}) == 1
+    rise = {k: svc.counters[k] - before[k] for k in before}
+    assert len(launches) == rise["launches"] >= 2   # this data escalates
+    budgets = [x["budget"] for x in launches]
+    assert budgets == sorted(set(budgets))
+    assert budgets[-1] == rs[0].meta["budget"]
+    rows = sum(len(b) for b in blocks)
+    for x in launches:
+        assert x["tier"] == "exact" and x["q"] == rows
+        assert len(x["num_candidates"]) == rows
+        assert x["dispatch_s"] >= 0 and x["wait_s"] >= 0
+    device_s = sum(x["dispatch_s"] + x["wait_s"] for x in launches)
+    for r in rs:
+        assert r.meta["queue_s"] >= 0
+        assert device_s <= r.latency_s
+    assert rise["microbatches"] == 1
+    assert rise["microbatch_requests"] == len(tickets)
+    assert rise["queue_s"] == pytest.approx(
+        sum(r.meta["queue_s"] for r in rs))
+    assert rise["host_s"] >= 0
+    if scenario == "wall_clock":
+        assert device_s > 0 and rise["host_s"] > 0
+    if scenario == "retry":
+        assert rise["launch_failures"] == 1 and rs[0].meta["attempts"] == 2
+
+
+def _host_spans(trace_dir) -> list:
+    """(start_ns, end_ns, name, args) of the ``svc.*`` spans in a profile."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            out += [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                     dict(e.stats)) for e in line.events
+                    if e.name.startswith("svc.")]
+    return sorted(out)
+
+
+def test_service_spans_nest_in_a_profile(index, queries, tmp_path):
+    import jax
+
+    svc, _ = make_service(index)
+    svc.search_sync("t", queries[:2], K)        # compile outside the profile
+    before = svc.counters["launches"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            tickets = [svc.submit("t", queries[i:i + 1], K) for i in range(2)]
+            svc.step()
+    finally:
+        jax.profiler.stop_trace()
+    assert all(t.done for t in tickets)
+    spans = _host_spans(tmp_path)
+    by = {}
+    for sp in spans:
+        by.setdefault(sp[2], []).append(sp)
+
+    def parent(sp, name):
+        [p] = [p for p in by[name] if p[0] <= sp[0] and sp[1] <= p[1]]
+        return p
+
+    assert len(by["svc.step"]) == 2
+    assert len(by["svc.microbatch"]) == 2
+    for mb in by["svc.microbatch"]:
+        parent(mb, "svc.step")
+        assert mb[3]["rows"] == 2 and mb[3]["bucket"] == 2
+    assert len(by["svc.launch"]) == svc.counters["launches"] - before
+    for sp in by["svc.launch"] + by["svc.resolve"]:
+        assert sp[3]["batch"] == parent(sp, "svc.microbatch")[3]["batch"]
+    for sp in by["svc.dispatch"] + by["svc.wait"]:
+        parent(sp, "svc.launch")
+    assert len(by["svc.dispatch"]) == len(by["svc.wait"]) == len(
+        by["svc.launch"])
+    assert {sp[3]["tier"] for sp in by["svc.launch"]} == {"exact"}
+
+
+# ---------------------------------------------------------------------------
 # Satellite: structured escalation stats + query validation
 # ---------------------------------------------------------------------------
 
