@@ -64,6 +64,24 @@ def test_search_program_names_its_phases(index, program, chunked,
     assert not any("bp.merge" in n for n in names)
 
 
+# A while nested anywhere inside the prune scan's body: the binary search
+# that once routed ranks to rows, run once per block.
+NESTED_WHILE = re.compile(r"bp\.prune/(.+/)?while/body/(.+/)?while(/|$)")
+
+
+def test_prune_scan_routes_without_a_search_loop(index):
+    """The exact batch program's ``bp.prune`` scan body holds no loop and
+    no ``searchsorted``: a per-block rank search cannot come back."""
+    ys = np.asarray(index.rows_view())[:4] + 0.05
+    br = search.resolve_block_rows(None, index.n, q=4, storage=index.storage)
+    text = search._knn_search_batch_jit.lower(
+        index, ys, K, 16, br).compile().as_text()
+    prune = [n for n in op_names(text) if "/bp.prune/" in n]
+    assert any("bp.prune/while/body/" in n for n in prune)
+    assert not [n for n in prune if "searchsorted" in n]
+    assert not [n for n in prune if NESTED_WHILE.search(n)]
+
+
 SHARDED = textwrap.dedent("""
     import json, re
     import numpy as np

@@ -373,3 +373,107 @@ def test_env_block_rows_choice_never_changes_results(quantize):
                                           block_rows=BLOCK_ROWS,
                                           env_block_rows=eb)
             _assert_bitwise_equal(got, base)
+
+
+# ---------------------------------------------------------------------------
+# Rank -> row routing of one block vs the binary-search oracle
+# ---------------------------------------------------------------------------
+
+def _rows_by_rank_searchsorted(admit, t_ranks):
+    """The binary search on the admit prefix-sum the scan routed by before:
+    the oracle of the sort that replaced it."""
+    bn = admit.shape[0]
+    csum = jnp.cumsum(admit, axis=0)
+    ranks = jnp.arange(1, t_ranks + 1, dtype=jnp.int32)
+    rows = jax.vmap(lambda c: jnp.searchsorted(c, ranks, side="left"))(csum.T)
+    return jnp.minimum(rows, bn - 1).astype(jnp.int32)
+
+
+ROUTE_BN, ROUTE_Q = BLOCK_ROWS, 6
+
+
+def _admit_tile(pattern):
+    bn, q = ROUTE_BN, ROUTE_Q
+    rows = np.arange(bn)[:, None]
+    rng = np.random.default_rng(11)
+    tiles = {
+        "all_zero": np.zeros((bn, q)),
+        "all_one": np.ones((bn, q)),
+        "single_row": np.broadcast_to(rows == 37, (bn, q)),
+        "alternating": np.broadcast_to(rows % 2 == 0, (bn, q)),
+        "last_8_rows": np.broadcast_to(rows >= bn - 8, (bn, q)),
+    }
+    if pattern in tiles:
+        return jnp.asarray(tiles[pattern], jnp.int32)
+    density = float(pattern.split("_")[1])
+    return jnp.asarray(rng.random((bn, q)) < density, jnp.int32)
+
+
+ROUTE_PATTERNS = ("all_zero", "all_one", "single_row", "alternating",
+                  "random_0.01", "random_0.5", "random_0.99", "last_8_rows")
+
+
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+@pytest.mark.parametrize("budget", [4 * BLOCK_ROWS, 20],
+                         ids=["budget_over_bn", "budget_under_bn"])
+@pytest.mark.parametrize("pattern", ROUTE_PATTERNS)
+def test_rows_by_rank_matches_searchsorted(pattern, budget, impl,
+                                           monkeypatch):
+    """The sort routing lists the same rows at every rank a fill writes.
+
+    Ranks below the block's admit count match the binary search exactly,
+    the rest stay inside the block, and ``_fill_block_slots`` returns the
+    same (sel, count) as with the binary search: from an empty carry,
+    mid-budget, near the budget's end and past it.
+    """
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", impl)
+    admit = _admit_tile(pattern)
+    bn, q = admit.shape
+    t_ranks = min(bn, budget)
+    got = np.asarray(jax.jit(search._rows_by_rank, static_argnums=1)(
+        admit, t_ranks))
+    want = np.asarray(_rows_by_rank_searchsorted(admit, t_ranks))
+    assert got.shape == want.shape == (q, t_ranks)
+    tot = np.asarray(admit).sum(axis=0)
+    for j in range(q):
+        live = min(tot[j], t_ranks)
+        np.testing.assert_array_equal(got[j, :live], want[j, :live])
+    assert got.min() >= 0 and got.max() < bn
+
+    count = jnp.asarray([0, 3, budget // 2, budget - 5, budget, budget + 7],
+                        jnp.int32)[:q]
+    sel = jnp.arange(q * budget, dtype=jnp.int32).reshape(q, budget)
+    off = jnp.int32(5 * bn)
+    got_sel, got_count = jax.jit(search._fill_block_slots, static_argnums=4)(
+        sel, count, admit, off, budget)
+    # The same fill routed by the binary search, run eagerly so the patched
+    # routing is what it calls.
+    monkeypatch.setattr(search, "_rows_by_rank", _rows_by_rank_searchsorted)
+    want_sel, want_count = search._fill_block_slots(sel, count, admit, off,
+                                                    budget)
+    np.testing.assert_array_equal(np.asarray(got_sel), np.asarray(want_sel))
+    np.testing.assert_array_equal(np.asarray(got_count),
+                                  np.asarray(want_count))
+
+
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+@pytest.mark.parametrize("quantize", [False, True])
+@pytest.mark.parametrize("budget", [N, 20],
+                         ids=["budget_over_bn", "budget_under_bn"])
+def test_stream_routing_matches_reference_per_kernel_impl(budget, quantize,
+                                                          impl, monkeypatch):
+    """The whole streamed pipeline, kernels as ``impl`` runs them, equals
+    the materialized mask's binary-search compaction bit for bit, with
+    budgets above a block's rows and below them (an overflowing one)."""
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", impl)
+    index, queries = _built("squared_euclidean", quantize)
+    br = search.resolve_block_rows(BLOCK_ROWS, index.n)
+
+    def run(streaming):
+        # A fresh function per call: the kernel path is fixed at trace time.
+        return jax.jit(lambda ix, ys: search._knn_search_batch_core(
+            ix, ys, K, budget, None, br, streaming=streaming))(index, queries)
+
+    got, want = run(True), run(False)
+    _assert_bitwise_equal(got, want)
+    assert bool(jnp.all(got.exact)) == (budget == N)
