@@ -76,6 +76,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import time
 from typing import NamedTuple
 
 import jax
@@ -182,6 +183,11 @@ class BatchStats(NamedTuple):
     budget_final: int       # the budget the returned launch ran with
     escalated_to_scan: bool  # cap exhausted -> full linear-scan fallback
     stopped_early: bool      # a stop_retry deadline ended the ladder
+    # One record per launch, in order: ``budget``, the largest Theorem-3
+    # union ``num_candidates``, and the host seconds spent dispatching
+    # (``dispatch_s``) and waiting for the device (``wait_s``) — the fields
+    # of the retrieval service's ``meta["launches"]``.
+    launches: tuple = ()
 
 
 def validate_queries(measure, q, *, mode: str = "raise"):
@@ -1323,7 +1329,10 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
 
     ``return_stats=True`` returns ``(SearchResult, BatchStats)`` — the
     structured escalation counters services and benchmarks alert on
-    (the log line is advisory only).
+    (the log line is advisory only) and one record per launch.  Each
+    launch runs inside a ``knn.launch`` profiler span (``budget``,
+    ``attempt``) around ``knn.dispatch`` and ``knn.wait``, as the
+    service's ``svc.launch``.
 
     ``target_recall`` (mutually exclusive with ``approx_p``) selects the
     approximate mode at a CALIBRATED shrink: the index's fitted recall
@@ -1352,14 +1361,33 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
         return knn_search_batch_approx(index, ys, k, b, p, block_rows,
                                        validate=False)
 
+    launches = []
+
+    def launch(b, attempt, thunk):
+        with jax.profiler.TraceAnnotation("knn.launch", budget=b,
+                                          attempt=attempt):
+            with jax.profiler.TraceAnnotation("knn.dispatch"):
+                t1 = time.perf_counter()
+                res = thunk()
+            with jax.profiler.TraceAnnotation("knn.wait"):
+                t2 = time.perf_counter()
+                jax.block_until_ready(res)
+                t3 = time.perf_counter()
+            launches.append({
+                "budget": int(b),
+                "num_candidates": int(np.asarray(res.num_candidates).max()),
+                "dispatch_s": t2 - t1, "wait_s": t3 - t2})
+        return res
+
     def done(res, escalations, scan=False, stopped=False):
         stats = BatchStats(escalations=escalations, budget_final=budget,
-                           escalated_to_scan=scan, stopped_early=stopped)
+                           escalated_to_scan=scan, stopped_early=stopped,
+                           launches=tuple(launches))
         return (res, stats) if return_stats else res
 
     for attempt in range(max_doublings + 1):
-        res = run(budget)
-        if bool(jnp.all(res.exact)) or budget >= index.n:
+        res = launch(budget, attempt, lambda: run(budget))
+        if bool(np.asarray(res.exact).all()) or budget >= index.n:
             return done(res, attempt)
         if attempt == max_doublings:
             break
@@ -1369,7 +1397,7 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
             # launching again.
             return done(res, attempt, stopped=True)
         # needed > budget on overflow, so the fitted budget strictly grows.
-        budget = fitted_budget(index, k, int(jnp.max(res.num_candidates)))
+        budget = fitted_budget(index, k, launches[-1]["num_candidates"])
     escalations = max_doublings
     if stop_retry is not None and stop_retry():
         return done(res, escalations, stopped=True)
@@ -1385,10 +1413,15 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
     # materialization here — the escalation is already the worst case.
     scan_index = (index.as_resident_forest()
                   if getattr(index, "is_tiered_store", False) else index)
-    ids, dists = _brute_force_live(scan_index, ys, k)
-    res = SearchResult(ids=ids, dists=dists,
-                       exact=jnp.ones(ys.shape[0], bool),
-                       num_candidates=res.num_candidates)
+    capped = res
+
+    def scan():
+        ids, dists = _brute_force_live(scan_index, ys, k)
+        return SearchResult(ids=ids, dists=dists,
+                            exact=jnp.ones(ys.shape[0], bool),
+                            num_candidates=capped.num_candidates)
+
+    res = launch(index.n, max_doublings + 1, scan)
     return done(res, escalations, scan=True)
 
 

@@ -23,6 +23,7 @@ becomes a plain inner product over subspaces — the whole filter phase is one
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +107,7 @@ def make_partition(d: int, m: int, order: np.ndarray | None = None) -> Partition
 # Transforms
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames=("family",))
 def p_transform_views(xs: Array, mask: Array, family: BregmanFamily) -> dict:
     """Alg. 2 on a PRE-GATHERED (..., M, w) subspace view.
 
@@ -114,6 +116,11 @@ def p_transform_views(xs: Array, mask: Array, family: BregmanFamily) -> dict:
     (core/segments.py) transforms new points with the SEALED partition's
     gathered view — share this math with :func:`p_transform`, mirroring
     the :func:`q_transform_views` split on the query side.
+
+    One jitted program, so the elementwise terms fuse into the reductions.
+    Run op by op at build size, each term was a table-sized temporary, and
+    how many of them were still held when the host had dispatched ahead
+    varied from run to run, and with it the build's memory peak.
     """
     alpha = jnp.sum(family.phi(xs) * mask, axis=-1)
     gamma = jnp.sum(xs * xs * mask, axis=-1)

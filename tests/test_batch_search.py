@@ -286,3 +286,93 @@ def test_build_index_logs_phase_seconds(caplog):
     assert set(s) == {"cost_model", "pccp", "kmeans", "total"}
     assert all(v >= 0.0 for v in s.values())
     assert s["total"] >= s["cost_model"] + s["pccp"] + s["kmeans"]
+
+
+@pytest.mark.parametrize("family,d,m", [("itakura_saito", 24, 4),
+                                        ("itakura_saito", 400, 50)],
+                         ids=["isd-d24-M4", "isd-d400-M50"])
+def test_knn_batch_ids_equal_brute_force(family, d, m):
+    """Exact knn_batch returns the linear scan's ids, in order, at Fonts'
+    width and partition count (d = 400, M = 50) as at a small d."""
+    data, queries, fam = _dataset(family, n=2000, d=d, q=8, seed=5)
+    index = build_index(data, family, m=m, num_clusters=16, seed=0)
+    k = 10
+    res = search.knn_batch(index, queries, k)
+    assert bool(jnp.all(res.exact))
+    bf_ids, bf_dists = search.brute_force_knn(data, queries, k, fam)
+    np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(bf_ids))
+    # The refine evaluates D_f as sum phi(x) - x . phi'(y) + c_y, which
+    # cancels terms of size sum |phi(y)| + |y phi'(y)| (the query's term
+    # scale); float32 rounding of those d-term sums leaves an error of a
+    # few ulps of that scale, which 1e-5 of it bounds with room at d = 400.
+    ys = np.asarray(queries, np.float64)
+    scale = np.sum(np.abs(np.asarray(fam.phi(ys)))
+                   + np.abs(ys * np.asarray(fam.phi_prime(ys))), axis=1)
+    err = np.abs(np.asarray(res.dists, np.float64)
+                 - np.asarray(bf_dists, np.float64))
+    assert np.all(err <= 1e-5 * scale[:, None])
+
+
+def _knn_spans(trace_dir) -> list:
+    """(start_ns, end_ns, name, args) of the ``knn.*`` spans in a profile."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            out += [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                     dict(e.stats)) for e in line.events
+                    if e.name.startswith("knn.")]
+    return sorted(out)
+
+
+@pytest.mark.parametrize("max_doublings", [8, 0], ids=["retry", "scan"])
+def test_knn_batch_one_span_and_record_per_launch(max_doublings, tmp_path):
+    """Each launch of knn_batch's ladder, the overflowing first budget, its
+    retry or the escalated scan, opens one ``knn.launch`` span around
+    ``knn.dispatch`` and ``knn.wait`` and leaves one BatchStats record."""
+    data, queries, _ = _dataset("squared_euclidean", n=400)
+    index = build_index(data, "squared_euclidean", m=4, num_clusters=8, seed=0)
+
+    def call():
+        return search.knn_batch(index, queries, 5, budget=8,
+                                max_doublings=max_doublings,
+                                return_stats=True)
+
+    call()                                      # compile outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res, stats = call()
+    finally:
+        jax.profiler.stop_trace()
+    assert bool(jnp.all(res.exact))
+    assert stats.escalated_to_scan == (max_doublings == 0)
+    records = stats.launches
+    assert len(records) == stats.escalations + 1 + int(
+        stats.escalated_to_scan) >= 2
+    assert records[0]["budget"] == 8
+    assert records[-1]["budget"] == (index.n if stats.escalated_to_scan
+                                     else stats.budget_final)
+    for r in records:
+        assert set(r) == {"budget", "num_candidates", "dispatch_s", "wait_s"}
+        assert r["dispatch_s"] >= 0 and r["wait_s"] >= 0
+    assert records[0]["num_candidates"] == int(
+        np.asarray(res.num_candidates).max()) > 8
+
+    spans = _knn_spans(tmp_path)
+    by = {}
+    for sp in spans:
+        by.setdefault(sp[2], []).append(sp)
+    launches = by["knn.launch"]
+    assert len(launches) == len(records)
+    assert [sp[3]["budget"] for sp in launches] == [r["budget"]
+                                                    for r in records]
+    assert [sp[3]["attempt"] for sp in launches] == sorted(
+        sp[3]["attempt"] for sp in launches)
+    for name in ("knn.dispatch", "knn.wait"):
+        assert len(by[name]) == len(launches)
+        for sp in by[name]:
+            [_] = [p for p in launches if p[0] <= sp[0] and sp[1] <= p[1]]

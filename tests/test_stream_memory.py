@@ -171,3 +171,21 @@ def test_streamed_results_match_reference_at_compile_shape_small():
     np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(ref.ids))
     np.testing.assert_array_equal(np.asarray(res.dists),
                                   np.asarray(ref.dists))
+
+
+@pytest.mark.parametrize("family", ["itakura_saito", "exponential"])
+def test_build_p_transform_holds_no_view_sized_temporary(family):
+    """``build_index``'s P-transform is one program: the generator terms
+    fuse into the per-subspace sums, so no (n, M, w) temporary exists.
+    Run op by op, each term was a table-sized buffer whose release waited
+    on the device, and the build's memory peak moved with how far the host
+    had dispatched ahead."""
+    from repro.core.bregman import get_family
+    from repro.core.transform import p_transform_views
+
+    n, m, w = N, 50, 8                      # Fonts' M and subspace width
+    compiled = p_transform_views.lower(
+        jax.ShapeDtypeStruct((n, m, w), jnp.float32),
+        jax.ShapeDtypeStruct((m, w), jnp.float32),
+        family=get_family(family)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * m * w * 4 // 8

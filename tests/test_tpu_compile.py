@@ -9,6 +9,7 @@ results or times.  The topology is described inside a fixture, never at
 import, because only one process may hold the TPU library at a time.
 """
 
+import functools
 import os
 
 import jax
@@ -26,16 +27,16 @@ SHAPES = [(54_387, 192, 28), (1_000_000, 256, 37), (745_000, 400, 50),
           (1_000_000, 960, 64)]
 
 
-def _refine(rows, grad, c_y):
-    return bregman_dist.bregman_refine_batch(rows, grad, c_y, "exponential")
+KERNELS = ["ub", "ub_quant", "prune", "prune_quant", "fused", "fused_quant",
+           "refine", "refine_quant"]
+# The refine is the one kernel whose body depends on the Bregman family
+# (its generator phi and pad value), so it is compiled for each family a
+# benchmark deployment runs: Deep's exponential and Fonts' Itakura-Saito.
+CASES = ([(kernel, "exponential") for kernel in KERNELS]
+         + [(kernel, "itakura_saito") for kernel in ("refine", "refine_quant")])
 
 
-def _refine_quant(codes, scale, zp, grad, c_y):
-    return bregman_dist.bregman_refine_batch_quant(codes, scale, zp, grad,
-                                                   c_y, "exponential")
-
-
-def _operands(kernel, bn, d, m):
+def _operands(kernel, family, bn, d, m):
     """(function, [(shape, dtype)]) of one kernel launch on a row block."""
     f32, i8 = jnp.float32, jnp.int8
     rows, row, qm = (bn, m), (bn,), (Q, m)
@@ -53,9 +54,12 @@ def _operands(kernel, bn, d, m):
                   [(rows, f32)] * 4 + [((Q,), f32)] + [(qm, f32)] * 3),
         "fused_quant": (bregman_fused.bregman_filter_prune_quant,
                         quant_rows * 4 + [((Q,), f32)] + [(qm, f32)] * 3),
-        "refine": (_refine,
+        "refine": (functools.partial(bregman_dist.bregman_refine_batch,
+                                     family=family),
                    [((Q, bn, d), f32), ((Q, d), f32), ((Q,), f32)]),
-        "refine_quant": (_refine_quant,
+        "refine_quant": (functools.partial(
+                             bregman_dist.bregman_refine_batch_quant,
+                             family=family),
                          [((Q, bn, d), i8), ((Q, bn), f32), ((Q, bn), f32),
                           ((Q, d), f32), ((Q,), f32)]),
     }[kernel]
@@ -92,14 +96,13 @@ def no_persistent_cache():
 
 @pytest.mark.parametrize("n,d,m", SHAPES, ids=[f"d{d}-M{m}"
                                                 for _, d, m in SHAPES])
-@pytest.mark.parametrize("kernel", ["ub", "ub_quant", "prune", "prune_quant",
-                                    "fused", "fused_quant", "refine",
-                                    "refine_quant"])
-def test_kernel_compiles_for_v5e(kernel, n, d, m, one_chip,
+@pytest.mark.parametrize("kernel,family", CASES, ids=[
+    k if f == "exponential" else f"{k}-{f}" for k, f in CASES])
+def test_kernel_compiles_for_v5e(kernel, family, n, d, m, one_chip,
                                  no_persistent_cache):
     storage = "int8" if kernel.endswith("quant") else "f32"
     bn = resolve_block_rows(None, n, q=Q, storage=storage)
-    fn, specs = _operands(kernel, bn, d, m)
+    fn, specs = _operands(kernel, family, bn, d, m)
     args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
