@@ -53,7 +53,10 @@ serving fast path:
 
 Refinement then runs ONE batched kernel call over all queries' candidate
 rows (kernels/bregman_dist.bregman_refine_batch) with per-query grad/c_y
-tiles.  The §8 approximate mode's CDF shrink is vectorized over the batch.
+tiles.  A launch whose candidate slots would gather at least the table's
+rows (:func:`dense_refine`, e.g. every budget-n launch) instead reads each
+stored row once for all its queries, in the kernel's shared-rows form.
+The §8 approximate mode's CDF shrink is vectorized over the batch.
 :func:`knn_batch` is the host wrapper: an iterative, capped
 budget-doubling loop shared by the whole batch.
 
@@ -184,9 +187,10 @@ class BatchStats(NamedTuple):
     escalated_to_scan: bool  # cap exhausted -> full linear-scan fallback
     stopped_early: bool      # a stop_retry deadline ended the ladder
     # One record per launch, in order: ``budget``, the largest Theorem-3
-    # union ``num_candidates``, and the host seconds spent dispatching
-    # (``dispatch_s``) and waiting for the device (``wait_s``) — the fields
-    # of the retrieval service's ``meta["launches"]``.
+    # union ``num_candidates``, whether the refine read the table in shared
+    # passes (``dense``, :func:`dense_refine`), and the host seconds spent
+    # dispatching (``dispatch_s``) and waiting for the device (``wait_s``)
+    # — the fields of the retrieval service's ``meta["launches"]``.
     launches: tuple = ()
 
 
@@ -316,10 +320,19 @@ def _candidate_mask(index: BallForest, q: dict, qb: Array) -> Array:
                          q["qconst"], q["sqrt_delta"], qb, sub_axis=-1)
 
 
-def _refine(index: BallForest, q: dict, sel: Array, valid: Array, k: int):
-    """Exact distances for one query's selected rows: the q=1 batch slice."""
+def _refine(index: BallForest, q: dict, mask: Array, totals: Array,
+            budget: int, k: int):
+    """Exact distances for one query's union ``mask`` (n,): the q=1 batch
+    slice.  ``budget`` slots hold its members first, by UB ``totals``; at
+    ``budget`` = n the shared pass reads the whole table instead."""
     qs1 = {"grad": q["grad"][None], "c_y": q["c_y"][None]}
-    ids, dists = _refine_batch(index, qs1, sel[None], valid[None], k)
+    if dense_refine(1, budget, index.n):
+        ids, dists = _refine_batch(index, qs1, None, mask[None], k)
+    else:
+        priority = jnp.where(mask, POS_BIG - totals, NEG_BIG - totals)
+        _, sel = jax.lax.top_k(priority, budget)
+        valid = jnp.take(mask, sel)
+        ids, dists = _refine_batch(index, qs1, sel[None], valid[None], k)
     return ids[0], dists[0]
 
 
@@ -362,11 +375,7 @@ def _knn_search_jit(index: BallForest, y: Array, k: int,
     num_candidates = jnp.sum(mask.astype(jnp.int32))
 
     # ---- static-budget selection: all union members first, by UB ----
-    priority = jnp.where(mask, POS_BIG - totals, NEG_BIG - totals)
-    _, sel = jax.lax.top_k(priority, budget)
-    valid = jnp.take(mask, sel)
-
-    ids, dists = _refine(index, q, sel, valid, k)
+    ids, dists = _refine(index, q, mask, totals, budget, k)
     exact = num_candidates <= budget
     return SearchResult(ids=ids, dists=dists, exact=exact,
                         num_candidates=num_candidates)
@@ -418,10 +427,7 @@ def _knn_search_approx_jit(
 
     mask = _candidate_mask(index, q, qb_approx)
     num_candidates = jnp.sum(mask.astype(jnp.int32))
-    priority = jnp.where(mask, POS_BIG - totals, NEG_BIG - totals)
-    _, sel = jax.lax.top_k(priority, budget)
-    valid = jnp.take(mask, sel)
-    ids, dists = _refine(index, q, sel, valid, k)
+    ids, dists = _refine(index, q, mask, totals, budget, k)
     return SearchResult(ids=ids, dists=dists, exact=num_candidates <= budget,
                         num_candidates=num_candidates)
 
@@ -734,7 +740,7 @@ def _stream_prune_compact(index: BallForest, qs: dict, qb: Array,
                           row_offset: Array | None = None,
                           fused: bool = True,
                           env_block_rows: int | None = None,
-                          with_tau: bool = False):
+                          with_tau: bool = False, row_mask: bool = False):
     """Streaming prune + compact: one scan, no (n, q) intermediates.
 
     A second ``lax.scan`` over the filter's ``block_rows`` blocks replaces
@@ -780,6 +786,11 @@ def _stream_prune_compact(index: BallForest, qs: dict, qb: Array,
     per-query min UB over admitted rows (+BIG when nothing admitted or
     ``with_tau`` is off — the fused kernel's UB output is only consumed,
     and on the jnp ref path only computed, when the caller asks).
+
+    ``row_mask`` (a launch the refine's shared pass takes) fills no slots:
+    each block writes which of its rows the slots would hold, its admitted
+    rows whose running member count stays within the budget, and the scan
+    returns ``sel`` None and ``valid`` as that (q, n) row mask.
     """
     from repro.kernels import ops as kernel_ops
     n = index.alpha_min_pt.shape[0]
@@ -872,21 +883,33 @@ def _stream_prune_compact(index: BallForest, qs: dict, qb: Array,
             if with_tau and ub is not None:
                 tau = jnp.minimum(
                     tau, jnp.min(jnp.where(admit > 0, ub, POS_BIG), axis=0))
+            if row_mask:
+                # A member of running rank r lands in slot r - 1.
+                rank = count + jnp.cumsum(admit, axis=0)        # (bn, q)
+                return sel, rank[-1], tau, (admit > 0) & (rank <= budget)
             sel, count = _fill_block_slots(sel, count, admit, off, budget)
             return sel, count, tau
 
+        def skip(args):
+            return args + (jnp.zeros((bn, q), bool),) if row_mask else args
+
         any_admit = jnp.any(env_admit)
-        sel, count, tau = jax.lax.cond(any_admit, run,
-                                       lambda args: args, (sel, count, tau))
+        sel, count, tau, *rows = jax.lax.cond(any_admit, run, skip,
+                                              (sel, count, tau))
         return (sel, count, admitted + env_admit.astype(jnp.int32),
-                blocks_run + any_admit.astype(jnp.int32), tau), None
+                blocks_run + any_admit.astype(jnp.int32), tau), (
+                    rows[0] if row_mask else None)
 
     # Unfilled slots hold n-1, matching _compact_candidates' clamp, so the
     # two implementations agree bit-for-bit on every output.
-    init = (jnp.full((q, budget), n - 1, jnp.int32),
+    init = (jnp.full((q, 0 if row_mask else budget), n - 1, jnp.int32),
             jnp.zeros((q,), jnp.int32), jnp.zeros((q,), jnp.int32),
             jnp.zeros((), jnp.int32), jnp.full((q,), POS_BIG, jnp.float32))
-    (sel, count, admitted, blocks_run, tau), _ = jax.lax.scan(step, init, xs)
+    (sel, count, admitted, blocks_run, tau), rows = jax.lax.scan(step, init,
+                                                                 xs)
+    if row_mask:
+        in_slot = rows.reshape(nb * bn, q)[:n].T                 # (q, n)
+        return None, in_slot, count, admitted, blocks_run, tau
     targets = jnp.arange(1, budget + 1, dtype=jnp.int32)
     valid = targets[None, :] <= jnp.minimum(count, budget)[:, None]
     return sel, valid, count, admitted, blocks_run, tau
@@ -914,39 +937,98 @@ def _refine_dists(index: BallForest, grad: Array, c_y: Array, sel: Array):
                                            index.family_name)
 
 
-# Largest candidate-row gather one refine step materializes.  A batch whose
-# (q, budget, d) gather is larger is refined in query chunks: an escalated
-# budget near n would otherwise gather q copies of the table at once.  The
-# int8 tier's per-row decode scalars ride as (budget, 1) columns, which a
-# TPU pads to 128 lanes, so its true footprint is several times the code
-# bytes counted here; the cap leaves room for that.
+def _shared_dists(index: BallForest, grad: Array, c_y: Array) -> Array:
+    """Exact distances of every stored row to each query -> (q, n).
+
+    The kernel's shared-rows form reads each row once for all the queries:
+    no candidate rows are gathered.
+    """
+    from repro.kernels import ops as kernel_ops
+    if index.storage == "int8":
+        return kernel_ops.bregman_refine_batch_quant(
+            index.data, index.data_scale, index.data_zp, grad, c_y,
+            index.family_name)
+    return kernel_ops.bregman_refine_batch(index.data, grad, c_y,
+                                           index.family_name)
+
+
+# Largest candidate-row gather one refine step materializes, and largest
+# (queries, n) float32 distance block of a shared pass.  A batch over the
+# cap is refined in query chunks: an escalated budget near n would
+# otherwise gather q copies of the table at once.  The int8 tier's per-row
+# decode scalars ride as (budget, 1) columns, which a TPU pads to 128
+# lanes, so its true gather footprint is several times the code bytes
+# counted here; the cap leaves room for that.
 REFINE_GATHER_BYTES = 1 << 28
 
 
-def _refine_batch(index: BallForest, qs: dict, sel: Array, valid: Array,
-                  k: int):
-    """Batched kernel calls refine all queries' candidate rows, then top-k."""
-    q, budget = sel.shape
-    per_query = budget * index.d * index.data.dtype.itemsize
-    chunk = max(1, min(q, REFINE_GATHER_BYTES // per_query))
-    if chunk == q:
-        dist = _refine_dists(index, qs["grad"], qs["c_y"], sel)
+def _shared_chunk(q: int, n: int) -> int:
+    """Queries per shared pass: its (chunk, n) float32 block under the cap."""
+    return max(1, min(q, REFINE_GATHER_BYTES // (4 * n)))
+
+
+def dense_refine(q: int, slots: int, n: int) -> bool:
+    """Whether a launch of ``q`` queries with ``slots`` candidate slots each
+    (its resolved budget) over ``n`` stored rows refines by shared passes
+    over the table.
+
+    The one rule, a pure function of the shapes: the jitted refine and the
+    host's per-launch ``dense`` records both call it.  The shared passes
+    read ``ceil(q / chunk) * n`` rows; the gather reads ``q * slots``.  A
+    sharded launch passes its shard's ``n``.
+    """
+    passes = -(-q // _shared_chunk(q, n))
+    return passes * n <= q * slots
+
+
+def _by_query_chunks(fn, chunk: int, *args):
+    """``fn(*args)`` over query chunks of ``chunk`` rows (lax.map)."""
+    q = args[0].shape[0]
+    if chunk >= q:
+        return fn(*args)
+    nc = -(-q // chunk)
+
+    def chunked(a):
+        pad = ((0, nc * chunk - q),) + ((0, 0),) * (a.ndim - 1)
+        return jnp.pad(a, pad).reshape((nc, chunk) + a.shape[1:])
+
+    out = jax.lax.map(lambda a: fn(*a), tuple(chunked(a) for a in args))
+    return jax.tree.map(lambda o: o.reshape((nc * chunk,) + o.shape[2:])[:q],
+                        out)
+
+
+def _refine_batch(index: BallForest, qs: dict, sel: Array | None,
+                  valid: Array, k: int):
+    """Exact distances of each query's candidates, then its top-k.
+
+    The candidates come as ``budget`` slots, rows ``sel`` (q, budget) with
+    ``valid`` (q, budget), whose rows the refine gathers; or, for a launch
+    that takes the shared pass (:func:`dense_refine`), as ``sel`` None and
+    a (q, n) row mask ``valid`` of the same rows.  The shared pass reads the
+    table once per query chunk and ranks the masked (q, n) distances: rows
+    in slot order, so ties and the rows past the last candidate (the
+    unfilled slots' row n-1) come out as the gather's.
+    """
+    q = valid.shape[0]
+    grad, c_y = qs["grad"], qs["c_y"]
+    if sel is None:
+        def ranked(grad, c_y, mask):
+            dist = jnp.where(mask, _shared_dists(index, grad, c_y), POS_BIG)
+            neg, rows = jax.lax.top_k(-dist, k)
+            return neg, jnp.where(jnp.take_along_axis(mask, rows, axis=1),
+                                  rows, index.n - 1)
+
+        neg, rows = _by_query_chunks(ranked, _shared_chunk(q, index.n),
+                                     grad, c_y, valid)
     else:
-        nc = -(-q // chunk)
-
-        def chunked(a):
-            pad = ((0, nc * chunk - q),) + ((0, 0),) * (a.ndim - 1)
-            return jnp.pad(a, pad).reshape((nc, chunk) + a.shape[1:])
-
-        dist = jax.lax.map(
-            lambda a: _refine_dists(index, *a),
-            (chunked(qs["grad"]), chunked(qs["c_y"]), chunked(sel)))
-        dist = dist.reshape(nc * chunk, budget)[:q]
-    dist = jnp.where(valid, dist, POS_BIG)
-    neg, pos = jax.lax.top_k(-dist, k)                  # (q, k)
-    ids = jnp.take(index.point_ids,
-                   jnp.take_along_axis(sel, pos, axis=1))
-    return ids, -neg
+        per_query = sel.shape[1] * index.d * index.data.dtype.itemsize
+        chunk = max(1, min(q, REFINE_GATHER_BYTES // per_query))
+        dist = _by_query_chunks(functools.partial(_refine_dists, index),
+                                chunk, grad, c_y, sel)
+        dist = jnp.where(valid, dist, POS_BIG)
+        neg, pos = jax.lax.top_k(-dist, k)              # (q, k)
+        rows = jnp.take_along_axis(sel, pos, axis=1)
+    return jnp.take(index.point_ids, rows), -neg
 
 
 def _knn_search_batch_core(index: BallForest, ys: Array, k: int, budget: int,
@@ -987,17 +1069,23 @@ def _knn_search_batch_core(index: BallForest, ys: Array, k: int, budget: int,
 
     # ---- phase 3+4: streaming prune + compact (block-skip from envelopes),
     # then one batched refine ----
+    shared = dense_refine(ys.shape[0], budget, index.n)
     with jax.named_scope("bp.prune"):
         if streaming:
             (sel, valid, num_candidates, env_admitted, blocks_run,
              tau) = _stream_prune_compact(index, qs, qb, budget, block_rows,
                                           fused=fused,
                                           env_block_rows=env_block_rows,
-                                          with_tau=with_stats and fused)
+                                          with_tau=with_stats and fused,
+                                          row_mask=shared)
         else:
             # Reference path: materialized (n, q) mask + (q, n) cumsum.
             mask = _candidate_mask_batch(index, qs, qb, block_rows)
             sel, valid, num_candidates = _compact_candidates(mask, budget)
+            if shared:                  # the slots' rows, as a row mask
+                member = mask.T.astype(jnp.int32)
+                sel, valid = None, (member > 0) & (
+                    jnp.cumsum(member, axis=1) <= budget)
             env_admitted = jnp.zeros((ys.shape[0],), jnp.int32)
             blocks_run = jnp.zeros((), jnp.int32)
             tau = jnp.full((ys.shape[0],), POS_BIG, jnp.float32)
@@ -1363,7 +1451,7 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
 
     launches = []
 
-    def launch(b, attempt, thunk):
+    def launch(b, attempt, thunk, dense):
         with jax.profiler.TraceAnnotation("knn.launch", budget=b,
                                           attempt=attempt):
             with jax.profiler.TraceAnnotation("knn.dispatch"):
@@ -1376,7 +1464,7 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
             launches.append({
                 "budget": int(b),
                 "num_candidates": int(np.asarray(res.num_candidates).max()),
-                "dispatch_s": t2 - t1, "wait_s": t3 - t2})
+                "dense": dense, "dispatch_s": t2 - t1, "wait_s": t3 - t2})
         return res
 
     def done(res, escalations, scan=False, stopped=False):
@@ -1386,7 +1474,10 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
         return (res, stats) if return_stats else res
 
     for attempt in range(max_doublings + 1):
-        res = launch(budget, attempt, lambda: run(budget))
+        # A tiered store refines by its own gather (core/tiered.py).
+        dense = (not getattr(index, "is_tiered_store", False)
+                 and dense_refine(ys.shape[0], budget, index.n))
+        res = launch(budget, attempt, lambda: run(budget), dense)
         if bool(np.asarray(res.exact).all()) or budget >= index.n:
             return done(res, attempt)
         if attempt == max_doublings:
@@ -1421,7 +1512,7 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
                             exact=jnp.ones(ys.shape[0], bool),
                             num_candidates=capped.num_candidates)
 
-    res = launch(index.n, max_doublings + 1, scan)
+    res = launch(index.n, max_doublings + 1, scan, False)
     return done(res, escalations, scan=True)
 
 

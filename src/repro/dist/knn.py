@@ -59,8 +59,9 @@ from repro.core.search import (MAX_BUDGET_DOUBLINGS,
                                SearchResult, _batch_filter_topk,
                                _cdf_shrink, _refine_batch,
                                _stream_prune_compact, _tuple_rows,
-                               fitted_budget_for_n, resolve_block_rows,
-                               resolve_budget, validate_p_guarantee)
+                               dense_refine, fitted_budget_for_n,
+                               resolve_block_rows, resolve_budget,
+                               validate_p_guarantee)
 from repro.core.transform import Partition, q_transform_views
 from . import sharding as shd
 
@@ -236,7 +237,8 @@ def _dist_knn_program(mesh: Mesh, axis: str, family_name: str,
         with jax.named_scope("bp.prune"):
             offset = jax.lax.axis_index(axis).astype(jnp.int32) * local.n
             sel_c, valid, ncand, _, _, _ = _stream_prune_compact(
-                local, qs, qb, budget, block_rows, row_offset=offset)
+                local, qs, qb, budget, block_rows, row_offset=offset,
+                row_mask=dense_refine(qb.shape[0], budget, local.n))
         with jax.named_scope("bp.refine"):
             ids, dists = _refine_batch(local, qs, sel_c, valid, k)
 

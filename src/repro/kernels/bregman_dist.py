@@ -7,6 +7,18 @@ the gradient inner product on the MXU, accumulated over d-tiles so the VMEM
 working set is (block_b x block_d) regardless of dimensionality.  The
 generator phi is selected statically per Bregman family (closure), so each
 family compiles its own fused kernel.
+
+The batch kernels take the candidate rows in one of two shapes:
+
+* per query, (q, b, d): each query's own gathered candidates -> (q, b);
+* shared, (n, d): one table that every query of the batch refines against
+  -> (q, n).  Each row tile is read from HBM once for the whole batch: its
+  sum_j phi(x_j) (and the int8 decode) is computed once, and the cross
+  term of every query is one (q, d) x (d, rows) MXU product with the row
+  axis on lanes.  A ragged last row tile is read past the table's end and
+  its columns are dropped, so the table is never padded or copied.  Where
+  the TPU stores the table column-major (:func:`stored_by_columns`), the
+  kernel reads it as the (d, n) array it is, with no transpose at all.
 """
 
 from __future__ import annotations
@@ -59,6 +71,91 @@ def _cross(x, grad):
                                preferred_element_type=jnp.float32)
 
 
+# f32 bytes of one shared-form row tile (rows x whole d): the double-buffered
+# input, its transpose and phi of it stay well inside a v5e core's VMEM.
+SHARED_TILE_BYTES = 1 << 20
+# Queries per grid step of the shared form; a larger batch takes more steps
+# over the same row tile, which stays resident between them.
+SHARED_QUERY_BLOCK = 256
+
+
+def stored_by_columns(n: int, d: int) -> bool:
+    """Whether a TPU's default layout keeps an (n, d) table column-major.
+
+    XLA orders the two dimensions so that the (8, 128) tiles pad less: a d
+    off the 128-lane grid (Fonts' 400, not Deep's 256) is stored as (d, n).
+    Reading the table in its stored order keeps XLA from copying it into
+    the other before the kernel; a wrong guess costs that copy, never a
+    wrong answer.
+    """
+    def padded(major, minor):
+        return -(-major // 8) * 8 * (-(-minor // 128) * 128)
+    return padded(d, n) < padded(n, d)
+
+
+def _tile_dists(phi, xt, grad, c):
+    """Distances of one (d, bb) row tile, rows on lanes, to a query block.
+
+    (d, bb) rows, (qb, d) phi'(y), (qb, 1) c_y -> (qb, bb)."""
+    fx = jnp.sum(phi(xt), axis=0, keepdims=True)               # (1, bb)
+    cross = jnp.dot(grad, xt, precision=F32_PRECISION,
+                    preferred_element_type=jnp.float32)        # (qb, bb)
+    return fx - cross + c
+
+
+def _shared_call(make_kernel, table, row_vectors, grad, c_y, block_b,
+                 interpret):
+    """Run a shared-form kernel over an (n, d) ``table`` and (n,)
+    ``row_vectors`` -> (q, n).
+
+    ``make_kernel(by_columns)`` builds the body for a table tile of
+    (d, bb) (the table stored column-major) or (bb, d).  Grid: row tiles
+    outer, query blocks inner, so each row tile is fetched once however
+    many query blocks the batch has.
+    """
+    n, d = table.shape
+    q = grad.shape[0]
+    if block_b is None:
+        block_b = max(128, SHARED_TILE_BYTES // (4 * d) // 128 * 128)
+    bb = n if n <= block_b else block_b
+    qb = q if q <= SHARED_QUERY_BLOCK else SHARED_QUERY_BLOCK
+    q_pad = -q % qb
+    g = jnp.pad(grad, ((0, q_pad), (0, 0)))
+    c = jnp.pad(c_y, (0, q_pad))[:, None]
+    by_columns = stored_by_columns(n, d)
+    if by_columns:
+        table = table.T
+        table_spec = pl.BlockSpec((d, bb), lambda i, qi: (0, i))
+    else:
+        table_spec = pl.BlockSpec((bb, d), lambda i, qi: (i, 0))
+    # Per-row vectors ride as (1, n) rows, one lane per table row.
+    lanes = pl.BlockSpec((1, bb), lambda i, qi: (0, i))
+    out = pl.pallas_call(
+        make_kernel(by_columns),
+        grid=(pl.cdiv(n, bb), (q + q_pad) // qb),
+        in_specs=[table_spec] + [lanes] * len(row_vectors) + [
+            pl.BlockSpec((qb, d), lambda i, qi: (qi, 0)),
+            pl.BlockSpec((qb, 1), lambda i, qi: (qi, 0)),
+        ],
+        out_specs=pl.BlockSpec((qb, bb), lambda i, qi: (qi, i)),
+        out_shape=jax.ShapeDtypeStruct((q + q_pad, n), jnp.float32),
+        interpret=interpret,
+    )(table, *[v[None] for v in row_vectors], g, c)
+    return out[:q] if q_pad else out
+
+
+def _make_shared_kernel(phi):
+    def make(by_columns):
+        def kernel(rows_ref, grad_ref, c_ref, out_ref):
+            x = rows_ref[...]
+            xt = x if by_columns else x.T                      # (d, bb)
+            out_ref[...] = _tile_dists(phi, xt, grad_ref[...], c_ref[...])
+
+        return kernel
+
+    return make
+
+
 def _make_batch_kernel(phi):
     def kernel(rows_ref, grad_ref, mask_ref, acc_ref):
         j = pl.program_id(2)
@@ -80,12 +177,12 @@ def _make_batch_kernel(phi):
     jax.jit, static_argnames=("family", "block_b", "block_d", "interpret")
 )
 def bregman_refine_batch(
-    rows: jax.Array,    # (q, b, d) per-query candidate rows
+    rows: jax.Array,    # (q, b, d) per-query candidate rows, or (n, d) shared
     grad: jax.Array,    # (q, d)    per-query phi'(y)
     c_y: jax.Array,     # (q,)      per-query additive constant
     family: str,
     *,
-    block_b: int = 256,
+    block_b: int | None = None,
     block_d: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
@@ -94,11 +191,19 @@ def bregman_refine_batch(
     The query axis rides the grid's outermost dimension, so every query's
     candidate tile reuses the same compiled body with its own grad/c_y tile —
     the batched analogue of :func:`bregman_refine` (one program, q x b rows).
+
+    Shared rows (n, d): D_f(rows[i], y_q) -> (q, n), each row read once for
+    every query (module docstring).  ``block_b`` is the row tile (default:
+    256 per query, ~``SHARED_TILE_BYTES`` of whole rows shared); the shared
+    form reads whole rows, so ``block_d`` tiles the per-query form only.
     """
     fam = get_family(family)
     phi = _PHIS[fam.name]
+    if rows.ndim == 2:
+        return _shared_call(_make_shared_kernel(phi), rows, (), grad, c_y,
+                            block_b, interpret)
     q, b, d = rows.shape
-    bb = min(block_b, max(8, b))
+    bb = min(block_b or 256, max(8, b))
     bd = min(block_d, max(128 if not interpret else 8, d))
     b_pad, d_pad = -b % bb, -d % bd
 
@@ -128,6 +233,23 @@ def bregman_refine_batch(
     return out[:, :b, 0] + c_y[:, None]
 
 
+def _make_quant_shared_kernel(phi, positive: bool):
+    def make(by_columns):
+        def kernel(codes_ref, scale_ref, zp_ref, grad_ref, c_ref, out_ref):
+            # The decode of core/quantize.dequantize_rows, once per row
+            # tile, with each row's (scale, zp) on its lane.
+            x = codes_ref[...].astype(jnp.float32)
+            xt = x if by_columns else x.T                      # (d, bb)
+            xt = xt * scale_ref[...] + zp_ref[...]
+            if positive:
+                xt = jnp.maximum(xt, DOMAIN_EPS)
+            out_ref[...] = _tile_dists(phi, xt, grad_ref[...], c_ref[...])
+
+        return kernel
+
+    return make
+
+
 def _make_quant_batch_kernel(phi, positive: bool):
     def kernel(codes_ref, scale_ref, zp_ref, grad_ref, mask_ref, acc_ref):
         j = pl.program_id(2)
@@ -155,14 +277,14 @@ def _make_quant_batch_kernel(phi, positive: bool):
     jax.jit, static_argnames=("family", "block_b", "block_d", "interpret")
 )
 def bregman_refine_batch_quant(
-    codes: jax.Array,   # (q, b, d) int8 candidate-row codes
-    scale: jax.Array,   # (q, b)    per-row affine scale
-    zp: jax.Array,      # (q, b)    per-row affine zero-point
+    codes: jax.Array,   # (q, b, d) int8 candidate-row codes, or (n, d) shared
+    scale: jax.Array,   # (q, b)    per-row affine scale, or (n,) shared
+    zp: jax.Array,      # (q, b)    per-row affine zero-point, or (n,) shared
     grad: jax.Array,    # (q, d)    per-query phi'(y)
     c_y: jax.Array,     # (q,)      per-query additive constant
     family: str,
     *,
-    block_b: int = 256,
+    block_b: int | None = None,
     block_d: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
@@ -173,13 +295,18 @@ def bregman_refine_batch_quant(
     dequantized values match ``core/quantize.dequantize_rows`` bit for bit
     so the reported distances are exact over the stored point set.
     Padded rows decode via (scale 0, zp 1) to the domain-safe ones-row;
-    padded columns carry code 0 with grad/mask 0.
+    padded columns carry code 0 with grad/mask 0.  Shared codes (n, d) with
+    (n,) decode scalars -> (q, n), each tile decoded once for every query;
+    the scalars ride as (1, n) rows, one lane per table row.
     """
     fam = get_family(family)
     phi = _PHIS[fam.name]
     positive = fam.name in POSITIVE_FAMILIES
+    if codes.ndim == 2:
+        return _shared_call(_make_quant_shared_kernel(phi, positive), codes,
+                            (scale, zp), grad, c_y, block_b, interpret)
     q, b, d = codes.shape
-    bb = min(block_b, max(32 if not interpret else 8, b))
+    bb = min(block_b or 256, max(32 if not interpret else 8, b))
     bd = min(block_d, max(128 if not interpret else 8, d))
     b_pad, d_pad = -b % bb, -d % bd
 

@@ -194,11 +194,13 @@ def bregman_refine(rows, grad, c_y, family: str, impl=None):
 
 
 def bregman_refine_batch(rows, grad, c_y, family: str, impl=None):
-    """Per-query exact distances.  (q,b,d),(q,d),(q,) -> (q,b)."""
-    if rows.ndim != 3 or grad.ndim != 2:
+    """Per-query exact distances.  (q,b,d),(q,d),(q,) -> (q,b); rows
+    (n,d) shared by every query -> (q,n)."""
+    if rows.ndim not in (2, 3) or grad.ndim != 2:
         raise ValueError(
-            "bregman_refine_batch wants (q,b,d)/(q,d), got "
-            f"{rows.shape}/{grad.shape}; use bregman_refine for one query")
+            "bregman_refine_batch wants (q,b,d) or shared (n,d) rows with "
+            f"(q,d) grads, got {rows.shape}/{grad.shape}; use "
+            "bregman_refine for one query")
     mode = _impl(impl)
     if mode == "ref":
         return ref.bregman_refine_batch(rows, grad, c_y, family)
@@ -208,11 +210,14 @@ def bregman_refine_batch(rows, grad, c_y, family: str, impl=None):
 
 def bregman_refine_batch_quant(codes, scale, zp, grad, c_y, family: str,
                                impl=None):
-    """Fused dequantize + exact distances.  (q,b,d) int8,(q,b),(q,b) -> (q,b)."""
-    if codes.ndim != 3 or scale.ndim != 2 or grad.ndim != 2:
+    """Fused dequantize + exact distances.  (q,b,d) int8,(q,b),(q,b) -> (q,b);
+    shared (n,d) codes with (n,) decode scalars -> (q,n)."""
+    if (codes.ndim not in (2, 3) or scale.ndim != codes.ndim - 1
+            or grad.ndim != 2):
         raise ValueError(
             "bregman_refine_batch_quant wants (q,b,d) codes with (q,b) "
-            f"decode rows, got {codes.shape}/{scale.shape}/{grad.shape}")
+            "decode rows, or shared (n,d) codes with (n,) ones, got "
+            f"{codes.shape}/{scale.shape}/{grad.shape}")
     mode = _impl(impl)
     if mode == "ref":
         return ref.bregman_refine_batch_quant(codes, scale, zp, grad, c_y,

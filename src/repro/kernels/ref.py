@@ -120,9 +120,10 @@ def bregman_refine_batch_quant(codes: Array, scale: Array, zp: Array,
                                grad: Array, c_y: Array, family: str) -> Array:
     """Fused dequantize + exact D_f over int8 candidate rows.
 
-    (q,b,d) int8 codes + (q,b) per-row scale/zp -> (q,b).  Decoding goes
-    through core/quantize.dequantize_rows itself, so the distances are
-    exact over the int8 tier's point set by construction.
+    (q,b,d) int8 codes + (q,b) per-row scale/zp -> (q,b), or shared (n,d)
+    codes + (n,) scale/zp -> (q,n).  Decoding goes through
+    core/quantize.dequantize_rows itself, so the distances are exact over
+    the int8 tier's point set by construction.
     """
     rows = qz.dequantize_rows(codes, scale, zp, get_family(family))
     return bregman_refine_batch(rows, grad, c_y, family)
@@ -137,10 +138,19 @@ def bregman_refine(rows: Array, grad: Array, c_y: Array, family: str) -> Array:
 
 def bregman_refine_batch(rows: Array, grad: Array, c_y: Array,
                          family: str) -> Array:
-    """Exact D_f per query's candidate rows.  (q,b,d),(q,d),(q,) -> (q,b)."""
+    """Exact D_f per query's candidate rows.  (q,b,d),(q,d),(q,) -> (q,b).
+
+    Shared rows (n,d) -> (q,n), one query at a time.  Both forms reduce each
+    (query, row) term over d in the same elementwise way, so a row's
+    distance is bit for bit the same whichever form computes it.
+    """
     fam = get_family(family)
-    fx = jnp.sum(fam.phi(rows), axis=-1)                  # (q, b)
-    cross = jnp.einsum("qbd,qd->qb", rows, grad, precision=F32_PRECISION)
+    fx = jnp.sum(fam.phi(rows), axis=-1)                  # (q, b) or (n,)
+    if rows.ndim == 2:
+        return jax.lax.map(
+            lambda a: fx - jnp.sum(rows * a[0], axis=-1) + a[1],
+            (grad, c_y))
+    cross = jnp.sum(rows * grad[:, None, :], axis=-1)     # (q, b)
     return fx - cross + c_y[:, None]
 
 

@@ -338,6 +338,9 @@ class RetrievalService:
             # microbatches' host time outside launch waits.
             "microbatches": 0, "microbatch_requests": 0,
             "queue_s": 0.0, "host_s": 0.0,
+            # Launches whose refine read the table in shared passes
+            # (core/search.dense_refine) instead of gathering per query.
+            "dense_refines": 0,
         }
         self._batches = 0           # microbatch ids, one per microbatch
 
@@ -883,12 +886,21 @@ class RetrievalService:
         def stop_retry() -> bool:
             return (self.clock.now() + tenant.cost.estimate()) > deadline
 
+        def dense(b: int) -> bool:
+            # The refine's path (core/search.dense_refine) over each
+            # shard's rows; a tiered store refines by its own gather.
+            if tenant.sharded is not None:
+                n = tenant.sharded.local_n
+                return bp.dense_refine(ys.shape[0], min(b, n), n)
+            return (tenant.tiered is None
+                    and bp.dense_refine(ys.shape[0], b, snapshot.n))
+
         if tenant.sharded is not None:
             budget = bp.default_budget(snapshot, k)
             if tier == QUALITY_PARTIAL:
                 budget = bp.fitted_budget(snapshot, k, 2 * k)
             res = self._launch(
-                tenant, tier, budget, mb, q,
+                tenant, tier, budget, mb, q, dense(budget),
                 lambda: dist_knn.distributed_knn(
                     tenant.sharded, ys,
                     family=tenant.family_name, k=k, budget=budget,
@@ -903,7 +915,7 @@ class RetrievalService:
         if tier == QUALITY_PARTIAL:
             budget = bp.fitted_budget(snapshot, k, 2 * k)
             res = self._launch(
-                tenant, tier, budget, mb, q,
+                tenant, tier, budget, mb, q, dense(budget),
                 lambda: bp.knn_search_batch(snapshot, ys, k, budget,
                                             block_rows=tenant.block_rows,
                                             validate=False))
@@ -914,13 +926,13 @@ class RetrievalService:
             b = budget
             if approx:
                 res = self._launch(
-                    tenant, tier, b, mb, q,
+                    tenant, tier, b, mb, q, dense(b),
                     lambda: bp.knn_search_batch_approx(
                         snapshot, ys, k, b, np.float32(p),
                         block_rows=tenant.block_rows, validate=False))
             else:
                 res = self._launch(
-                    tenant, tier, b, mb, q,
+                    tenant, tier, b, mb, q, dense(b),
                     lambda: bp.knn_search_batch(snapshot, ys, k, b,
                                                 block_rows=tenant.block_rows,
                                                 validate=False))
@@ -935,11 +947,12 @@ class RetrievalService:
                 snapshot, k, int(np.asarray(res.num_candidates).max()))
 
     def _launch(self, tenant: Tenant, tier: str, budget: int, mb: dict,
-                q: int, thunk):
+                q: int, dense: bool, thunk):
         """One guarded launch: faults, timing, cost model, breaker.
 
         A completed launch appends its record to ``mb["launches"]``: tier,
-        budget, real query rows ``q``, the seconds spent dispatching and
+        budget, real query rows ``q``, whether the refine read the table in
+        shared passes (``dense``), the seconds spent dispatching and
         waiting for the device, and the rows' Theorem-3 union sizes.
         """
         cfg = self.config
@@ -986,11 +999,12 @@ class RetrievalService:
                 self.clock.sleep(extra)
         elapsed = self.clock.now() - t0
         mb["launches"].append({
-            "tier": tier, "budget": int(budget), "q": q,
+            "tier": tier, "budget": int(budget), "q": q, "dense": dense,
             "dispatch_s": t2 - t1, "wait_s": t3 - t2,
             "num_candidates": np.asarray(res.num_candidates)[:q].tolist()})
         tenant.cost.observe(elapsed)
         self.counters["launches"] += 1
+        self.counters["dense_refines"] += int(dense)
         if self.faults is not None:
             self.faults.after_launch(tenant.name, tier, attempt,
                                      tenant_obj=tenant, service=self)

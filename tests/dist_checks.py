@@ -6,6 +6,7 @@ Exits non-zero on any failure.
 """
 
 import os
+import sys
 
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
@@ -58,6 +59,76 @@ def check_distributed_knn():
             assert len(got_ids & want_ids) >= k - 1, (got_ids, want_ids)
         print(f"  knn ok on mesh {dict(zip(axes, mesh_shape, strict=True))} "
               f"(candidates={np.asarray(ncand).tolist()})")
+
+
+def check_sharded_shared_pass():
+    """Each shard's refine takes the shared pass where the rule says so for
+    its own rows (budget = local_n, and just under, at and over the
+    threshold), on shards whose rows start at a nonzero offset: its
+    answers equal a forced per-query gather's (bit for bit on the
+    reference backend, to rounding in the Pallas interpreter) and, at
+    budget = local_n, the single-host search's."""
+    mesh = make_mesh((4,), ("data",))
+    family = "exponential"
+    fam = get_family(family)
+    n, d, q, k = 1200, 24, 5, 7
+    local_n = n // 4                        # 300: a ragged last row tile
+    data = np.asarray(fam.sample(jax.random.PRNGKey(0), (n, d)))
+    queries = jnp.asarray(fam.sample(jax.random.PRNGKey(1), (q, d)))
+    threshold = -(-local_n // q)            # one pass: q * budget >= local_n
+    shared_calls = []
+    shared_dists = search._shared_dists
+
+    def counted(*args):                     # traced once per program
+        shared_calls.append(1)
+        return shared_dists(*args)
+
+    search._shared_dists = counted
+    for impl in ("ref", "interpret"):
+        os.environ["REPRO_KERNEL_IMPL"] = impl
+        for storage in ("f32", "int8"):
+            forest = build_index(data, family, m=4, num_clusters=16, seed=0,
+                                 quantize=storage == "int8")
+            sharded = dknn.shard_index(forest, mesh)
+            assert sharded.local_n == local_n
+            yv = dknn.query_subview(forest.partition, queries)
+            for budget in (local_n, threshold - 1, threshold, threshold + 1):
+                dense = search.dense_refine(q, budget, local_n)
+                runs = {}
+                for path in ("rule", "gather"):
+                    dknn._dist_knn_program.cache_clear()
+                    if path == "gather":
+                        dknn.dense_refine = lambda *a: False
+                    del shared_calls[:]
+                    runs[path] = dknn.distributed_knn(
+                        sharded, yv, family=family, k=k, budget=budget,
+                        mesh=mesh, max_doublings=0)
+                    dknn.dense_refine = search.dense_refine
+                    assert bool(shared_calls) == (path == "rule" and dense), (
+                        impl, storage, budget, path)
+                got, want = runs["rule"], runs["gather"]
+                for f in ("ids", "exact", "num_candidates"):
+                    np.testing.assert_array_equal(
+                        np.asarray(getattr(got, f)),
+                        np.asarray(getattr(want, f)))
+                if impl == "ref":
+                    np.testing.assert_array_equal(np.asarray(got.dists),
+                                                  np.asarray(want.dists))
+                else:
+                    np.testing.assert_allclose(np.asarray(got.dists),
+                                               np.asarray(want.dists),
+                                               rtol=1e-5, atol=1e-4)
+                if budget == local_n:
+                    one = search.knn_search_batch(forest, queries, k, n)
+                    assert bool(jnp.all(got.exact))
+                    np.testing.assert_array_equal(np.asarray(got.ids),
+                                                  np.asarray(one.ids))
+                    np.testing.assert_allclose(np.asarray(got.dists),
+                                               np.asarray(one.dists),
+                                               rtol=1e-5, atol=1e-4)
+            print(f"  sharded shared pass ok ({impl}, {storage})")
+    search._shared_dists = shared_dists
+    os.environ.pop("REPRO_KERNEL_IMPL")
 
 
 def check_collective_matmul():
@@ -153,11 +224,15 @@ def check_pipeline():
     print("  pipeline ok")
 
 
+CHECKS = (check_collective_matmul, check_compression, check_pipeline,
+          check_distributed_knn)
+
+
 if __name__ == "__main__":
+    # python tests/dist_checks.py [check_name ...]: the named checks only
+    # (any of this file's check_* functions), else every one in CHECKS.
     assert jax.device_count() == 8, jax.device_count()
     print("distributed checks on", jax.device_count(), "devices")
-    check_collective_matmul()
-    check_compression()
-    check_pipeline()
-    check_distributed_knn()
+    for name in sys.argv[1:] or [c.__name__ for c in CHECKS]:
+        globals()[name]()
     print("ALL DISTRIBUTED CHECKS PASSED")

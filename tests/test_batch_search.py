@@ -247,9 +247,14 @@ def test_batched_refine_kernel_matches_ref(family):
 
 
 def test_refine_batch_dispatch_rejects_bad_rank():
+    """Rows are (q, b, d) per query or (n, d) shared; a single query's (d,)
+    row and a (d,) grad belong to bregman_refine."""
     with pytest.raises(ValueError, match="bregman_refine_batch"):
-        ops.bregman_refine_batch(jnp.zeros((4, 8)), jnp.zeros((4, 8)),
+        ops.bregman_refine_batch(jnp.zeros((8,)), jnp.zeros((4, 8)),
                                  jnp.zeros((4,)), "squared_euclidean")
+    with pytest.raises(ValueError, match="bregman_refine_batch"):
+        ops.bregman_refine_batch(jnp.zeros((4, 8)), jnp.zeros((8,)),
+                                 jnp.zeros(()), "squared_euclidean")
 
 
 @pytest.mark.parametrize("storage", ["f32", "int8"])
@@ -357,7 +362,8 @@ def test_knn_batch_one_span_and_record_per_launch(max_doublings, tmp_path):
     assert records[-1]["budget"] == (index.n if stats.escalated_to_scan
                                      else stats.budget_final)
     for r in records:
-        assert set(r) == {"budget", "num_candidates", "dispatch_s", "wait_s"}
+        assert set(r) == {"budget", "num_candidates", "dense", "dispatch_s",
+                          "wait_s"}
         assert r["dispatch_s"] >= 0 and r["wait_s"] >= 0
     assert records[0]["num_candidates"] == int(
         np.asarray(res.num_candidates).max()) > 8

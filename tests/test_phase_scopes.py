@@ -43,9 +43,11 @@ def index(request):
 def test_search_program_names_its_phases(index, program, chunked,
                                          monkeypatch):
     """Both refine layouts keep the gather in its scope: one gather of all
-    queries, and the query chunks of a gather over the byte cap."""
+    queries, and the query chunks of a gather over the byte cap.  Both
+    budgets stay under ``dense_refine``'s threshold, so the refine gathers."""
     ys = np.asarray(index.rows_view())[:4] + 0.05
-    budget = N if chunked else 16
+    budget = N // 8 if chunked else 16
+    assert not search.dense_refine(4, budget, index.n)
     if chunked:                         # two queries per refine chunk
         monkeypatch.setattr(search, "REFINE_GATHER_BYTES",
                             2 * budget * D * index.data.dtype.itemsize)

@@ -11,6 +11,7 @@ import, because only one process may hold the TPU library at a time.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -106,3 +107,52 @@ def test_kernel_compiles_for_v5e(kernel, family, n, d, m, one_chip,
     args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+# The refine's shared-rows form at the (n, d) of Deep and Fonts, where it
+# reads the whole table once for a 32- or 64-query launch.
+SHARED = [(1_000_000, 256, "exponential"), (745_000, 400, "itakura_saito")]
+
+
+@pytest.mark.parametrize("q", [32, 64])
+@pytest.mark.parametrize("storage", ["f32", "int8"])
+@pytest.mark.parametrize("n,d,family", SHARED,
+                         ids=[f"d{d}-{f}" for _, d, f in SHARED])
+def test_shared_refine_compiles_for_v5e(n, d, family, storage, q, one_chip,
+                                        no_persistent_cache):
+    """The shared form compiles under its kernel's own name and reads the
+    table in its stored layout: no copy, transpose or pad of the table."""
+    f32, i8 = jnp.float32, jnp.int8
+    if storage == "int8":
+        fn = functools.partial(bregman_dist.bregman_refine_batch_quant,
+                               family=family)
+        specs = [((n, d), i8), ((n,), f32), ((n,), f32), ((q, d), f32),
+                 ((q,), f32)]
+    else:
+        fn = functools.partial(bregman_dist.bregman_refine_batch,
+                               family=family)
+        specs = [((n, d), f32), ((q, d), f32), ((q,), f32)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in specs]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    name = fn.func.__name__
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and f"%{name}." in calls[0]
+    assert f"[{q},{n}]" in calls[0].split("=")[1]
+    table = f"[{n},{d}]"
+    moved = [ln for ln in text.splitlines() if "=" in ln
+             and table in ln.split("=")[1].split("(")[0]
+             and re.search(r"%(copy|transpose|pad|fusion)", ln.split("=")[0])]
+    assert not moved, moved
+
+
+@pytest.mark.parametrize("n,d", [(1_000_000, 256), (745_000, 400),
+                                 (54_387, 192), (1_000_000, 960), (300, 400),
+                                 (100, 500), (1_000, 1_000)])
+def test_stored_by_columns_matches_v5e_layout(n, d, one_chip,
+                                              no_persistent_cache):
+    """The shared refine's guess of a table's stored order is the order a
+    v5e's default layout gives it."""
+    arg = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda x: x + x).lower(arg).compile().as_text()
+    order = re.search(rf"\[{n},{d}\]\{{(\d),(\d)", text).groups()
+    assert bregman_dist.stored_by_columns(n, d) == (order == ("0", "1"))
