@@ -1,11 +1,7 @@
-"""Batched-query throughput: fused knn_search_batch vs vmapped per-query.
+"""Batched-query throughput of the fused knn_search_batch pipeline.
 
 Measures queries/sec for q in {1, 8, 64, 256} on one synthetic dataset so
-BENCH json tracks batch throughput over time.  The baseline is the honest
-pre-fusion batch path — ``jax.vmap`` of the single-query jit core at the
-same static budget — which pays per-query cluster-pruning gathers and a
-full-n budget top_k per query; the fused pipeline replaces those with one
-broadcasted compare and a streaming scatter compaction.
+BENCH json tracks batch throughput over time.
 
 Two streaming-specific columns ride every fused row (and the BENCH
 trajectory): ``skip_rate`` — the fraction of (block, query) tiles pruned
@@ -19,8 +15,6 @@ that used to thrash on the (n, q) mask and now skips whole blocks.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,14 +26,6 @@ from repro.core import search
 from .common import Row, timeit
 
 BATCH_SIZES = (1, 8, 64, 256)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "budget"))
-def _vmapped_baseline(index, ys, k, budget):
-    # validate=False: the host-side domain gate cannot run on a vmap
-    # tracer (the synthetic queries are valid by construction here).
-    return jax.vmap(lambda y: search.knn_search(index, y, k, budget,
-                                                validate=False))(ys)
 
 
 def _peak_temp_bytes(index, ys, k, budget, block_rows):
@@ -92,17 +78,10 @@ def run(scale: float = 1.0):
     for q in BATCH_SIZES:
         ys = jnp.asarray(np.asarray(
             fam.sample(jax.random.PRNGKey(1), (q, d), scale=1.0)))
-        us_base = timeit(lambda: _vmapped_baseline(index, ys, k, budget),
-                         repeats=5)
         us_fused = timeit(
             lambda: search.knn_search_batch(index, ys, k, budget), repeats=5)
-        qps_base = q / (us_base / 1e6)
-        qps_fused = q / (us_fused / 1e6)
-        rows.append(Row("batch_search", f"vmap_q{q}", us_base,
-                        {"n": n, "qps": round(qps_base, 1)}))
         rows.append(Row("batch_search", f"fused_q{q}", us_fused,
-                        {"n": n, "qps": round(qps_fused, 1),
-                         "speedup": round(us_base / us_fused, 2),
+                        {"n": n, "qps": round(q / (us_fused / 1e6), 1),
                          **_stream_cols(index, ys, k, budget)}))
 
     # Large-n clustered shape: the regime that used to hold ~5 n*q bytes of

@@ -40,8 +40,11 @@ fit parameters for both merge and rebuild.  See docs/accuracy.md.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 logger = logging.getLogger(__name__)
@@ -135,6 +138,23 @@ def held_out_queries(index, num_queries: int, seed: int,
     return np.asarray(qs, np.float32)
 
 
+@functools.partial(jax.jit, static_argnames=("k",))
+def _brute_force_live(index, ys, k: int):
+    """Linear scan over the LIVE rows of an index — the calibration oracle.
+
+    Tombstoned/padded rows (``point_ids < 0``, whose data is the inert
+    ones-fill at a finite distance) are masked, so a mutated index never
+    surfaces a deleted id.  ``rows_view`` decodes the int8 tier, so the
+    scan is exact over the stored point set there too.
+    """
+    fam = index.family
+    rows = index.rows_view()
+    dist = jax.vmap(lambda y: fam.distance(rows, y[None, :]))(ys)
+    dist = jnp.where((index.point_ids >= 0)[None, :], dist, 1e30)
+    neg, idx = jax.lax.top_k(-dist, k)                  # (q, k)
+    return jnp.take(index.point_ids, idx), -neg
+
+
 def fit_calibration(index, *, k: int = 10,
                     num_queries: int = DEFAULT_NUM_QUERIES,
                     p_grid=None, seed: int = 0,
@@ -147,7 +167,7 @@ def fit_calibration(index, *, k: int = 10,
     pipeline searches.  ``p`` rides the grid as a traced scalar, so the
     whole sweep compiles once.
     """
-    from .search import _as_forest, _brute_force_live, knn_batch
+    from .search import _as_forest, knn_batch
     forest = _as_forest(index, k)
     grid = np.asarray(DEFAULT_P_GRID if p_grid is None else p_grid,
                       np.float64)

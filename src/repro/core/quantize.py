@@ -168,7 +168,7 @@ def ub_slack(alpha_scale: Array, sg_scale: Array, sqrt_delta: Array) -> Array:
     Returns the (…, M) componentwise inflation whose row sum dominates
     the worst-case decoded-vs-true UB error of any row that could have
     determined the k-th bound (docs/quantization.md).  Shared by the
-    single-query, batched, and distributed bound computations so the
+    batched and distributed bound computations so the
     admissibility-critical expression exists exactly once.
     """
     return UB_SLACK * (alpha_scale[..., None]

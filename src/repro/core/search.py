@@ -2,24 +2,22 @@
 
 TPU execution model: everything after the query transform is one jit'd
 program with static shapes.  The dynamic-size candidate set of the paper is
-realized as a static ``budget``-sized selection with an exactness flag
-(DESIGN.md §6, item 5); :func:`knn_search` is the jit core and
-:func:`knn` is the host wrapper that doubles the budget on overflow, so
-results are ALWAYS exact for the exact mode.
+realized as a static ``budget``-sized selection with a per-query exactness
+flag.  There is one pipeline, for a (q, d) block of queries
+(:func:`knn_search_batch`); a single query is its q = 1 row (:func:`knn`).
+One host loop, :func:`run_ladder`, grows the budget when a union overflows,
+so results are ALWAYS exact in the exact mode.
 
-Pipeline per query (Alg. 6):
+Pipeline per query block (Alg. 6):
   1. Q-transform (O(d)).
   2. UB filter over all points — matmul form (kernels/bregman_ub).
   3. tau = k-th smallest UB; per-subspace bounds qb (Alg. 4).
-  4. Ball pruning per subspace (tuple-space LB, DESIGN §3.3); candidate mask
-     = union over subspaces (Theorem 3).
+  4. Ball pruning per subspace (tuple-space LB); candidate set = union
+     over subspaces (Theorem 3).
   5. Refine selected candidates with exact D_f (kernels/bregman_dist),
      global top-k.
 
-Batched pipeline (:func:`knn_search_batch`): a (q, d) query block runs the
-same five phases end-to-end as ONE jitted program instead of a vmap of the
-single-query core, with three structural differences that make it the
-serving fast path:
+The phases, as one jitted program:
 
   * **Filter** — the q per-query UB passes collapse onto a single
     (n, M) x (M, q) ``bregman_ub_matrix`` call (the MXU matmul form), and
@@ -40,16 +38,14 @@ serving fast path:
     corner recompute, compare, and mask emit in one VMEM-resident pass),
     sort each query's admitted rows of the block into row order, and
     write them into their static (q, budget) candidate slots at the
-    running member count carried across blocks.  The historical (n, q)
-    bool mask, (q, n) int32 cumsum, and per-query binary searches are
-    gone: peak intermediate memory is O(block_rows * q + q * budget),
-    independent of n (guarded by the hlo-analysis regression test in
-    tests/test_stream_memory.py).  Slot order is index order, not UB
-    order; when the union overflows the budget the overflowing queries
-    are flagged ``exact=False`` and the host wrapper retries, exactly
-    like the single-query path.  (:func:`knn_search_batch_reference`
-    keeps the materialized mask/cumsum implementation as the bit-parity
-    oracle for tests and benchmarks.)
+    running member count carried across blocks.  Peak intermediate
+    memory is O(block_rows * q + q * budget), independent of n (guarded
+    by the hlo-analysis regression test in tests/test_stream_memory.py).
+    Slot order is index order, not UB order; when the union overflows the
+    budget the overflowing queries are flagged ``exact=False`` and the
+    ladder launches again.  (:func:`knn_search_batch_reference` keeps the
+    materialized mask/cumsum implementation as the bit-parity oracle for
+    tests and benchmarks.)
 
 Refinement then runs ONE batched kernel call over all queries' candidate
 rows (kernels/bregman_dist.bregman_refine_batch) with per-query grad/c_y
@@ -57,8 +53,7 @@ tiles.  A launch whose candidate slots would gather at least the table's
 rows (:func:`dense_refine`, e.g. every budget-n launch) instead reads each
 stored row once for all its queries, in the kernel's shared-rows form.
 The §8 approximate mode's CDF shrink is vectorized over the batch.
-:func:`knn_batch` is the host wrapper: an iterative, capped
-budget-doubling loop shared by the whole batch.
+:func:`knn_batch` is the host wrapper: the ladder over the whole batch.
 
 Every public entry point also accepts the mutable
 :class:`~repro.core.segments.SegmentedForest`: it is snapshotted to its
@@ -78,7 +73,6 @@ over the decoded point set at ~4x lower filter traffic
 from __future__ import annotations
 
 import functools
-import logging
 import time
 from typing import NamedTuple
 
@@ -95,10 +89,7 @@ from . import quantize as qz
 
 Array = jax.Array
 
-NEG_BIG = -1e30
 POS_BIG = 1e30
-
-logger = logging.getLogger(__name__)
 
 # Default row-block size for the streaming batched pipeline; one block is
 # the unit of VMEM residency (the TPU analogue of the paper's disk page,
@@ -176,15 +167,12 @@ class SearchResult(NamedTuple):
 class BatchStats(NamedTuple):
     """Structured retry telemetry from :func:`knn_batch`.
 
-    The budget-escalation path used to announce itself only through a log
-    line; services and benchmarks alert on THESE counters instead of
-    scraping logs (``escalations`` growing under load means the default
-    budget is undersized; ``escalated_to_scan`` should be ~never).
+    Services and benchmarks alert on these counters: ``escalations``
+    growing under load means the default budget is undersized.
     """
 
     escalations: int        # budget-growth retries taken (0 = first try fit)
     budget_final: int       # the budget the returned launch ran with
-    escalated_to_scan: bool  # cap exhausted -> full linear-scan fallback
     stopped_early: bool      # a stop_retry deadline ended the ladder
     # One record per launch, in order: ``budget``, the largest Theorem-3
     # union ``num_candidates``, whether the refine read the table in shared
@@ -197,7 +185,7 @@ class BatchStats(NamedTuple):
 def validate_queries(measure, q, *, mode: str = "raise"):
     """Admission gate: reject NaN / out-of-domain query rows up front.
 
-    ``knn_search``/``knn_search_batch`` math silently returns garbage for a
+    The ``knn_search_batch`` math silently returns garbage for a
     query outside the generator's open domain (a non-positive entry under
     Itakura-Saito/Burg/Shannon, any NaN/inf anywhere): the UB matmul and
     the refine kernel both produce NaNs that ``top_k`` resolves to
@@ -215,7 +203,7 @@ def query_struct(y: Array, partition, family) -> dict:
     """Everything the pipeline needs about a query (or (q, d) block).
 
     Per-subspace triples (Alg. 3) plus the refine constants — the query
-    representation of the single-query and batched paths.  The distributed
+    representation of the batched pipeline.  The distributed
     path (dist/knn.py) builds the same dict from its pre-gathered subspace
     view via ``transform.q_transform_views`` + ``query_refine_constants``
     instead of calling this (the gather is hoisted to the host there).
@@ -284,16 +272,6 @@ def _qb_slack(index: BallForest, idx: Array, sqrt_delta: Array):
     return qz.ub_slack(a_s, g_s, sqrt_delta)
 
 
-def _corner_tables(index: BallForest) -> tuple[Array, Array]:
-    """Full (n, M) fp32 corner tables (decoded in the int8 tier).
-
-    The int8 corners were DIRECTED-rounded at build (alpha_min floored,
-    sqrt_gamma_max ceiled), so the decoded values are conservative and the
-    Theorem-3 admission below needs no slack term.
-    """
-    return qz.decoded_corner_tables(index)
-
-
 def _corner_admit(amin_pt: Array, gmax_pt: Array, qconst: Array,
                   sqrt_delta: Array, qb: Array, sub_axis: int) -> Array:
     """THE Theorem-3 membership test, shared by every search path.
@@ -313,148 +291,11 @@ def _corner_admit(amin_pt: Array, gmax_pt: Array, qconst: Array,
     return jnp.any(lb <= qb, axis=sub_axis)
 
 
-def _candidate_mask(index: BallForest, q: dict, qb: Array) -> Array:
-    """Theorem-3 union membership for one query. (n,) bool."""
-    amin, gmax = _corner_tables(index)
-    return _corner_admit(amin, gmax,
-                         q["qconst"], q["sqrt_delta"], qb, sub_axis=-1)
-
-
-def _refine(index: BallForest, q: dict, mask: Array, totals: Array,
-            budget: int, k: int):
-    """Exact distances for one query's union ``mask`` (n,): the q=1 batch
-    slice.  ``budget`` slots hold its members first, by UB ``totals``; at
-    ``budget`` = n the shared pass reads the whole table instead."""
-    qs1 = {"grad": q["grad"][None], "c_y": q["c_y"][None]}
-    if dense_refine(1, budget, index.n):
-        ids, dists = _refine_batch(index, qs1, None, mask[None], k)
-    else:
-        priority = jnp.where(mask, POS_BIG - totals, NEG_BIG - totals)
-        _, sel = jax.lax.top_k(priority, budget)
-        valid = jnp.take(mask, sel)
-        ids, dists = _refine_batch(index, qs1, sel[None], valid[None], k)
-    return ids[0], dists[0]
-
-
-def _single_filter(index: BallForest, q: dict, k: int):
-    """Filter phase for one query: (totals (n,), top-k idx (k,), qb (M,)).
-
-    f32 storage runs the original ub_filter; the int8 tier streams the
-    codes through the quantized UB kernel and inflates the Alg.-4 bounds
-    by the stat rounding slack (`_qb_slack`) so the downstream prune stays
-    admissible over the decoded point set.
-    """
-    from repro.kernels import ops as kernel_ops
-    if index.storage == "int8":
-        totals = kernel_ops.bregman_ub_matrix_quant(
-            index.alpha, index.alpha_scale, index.alpha_zp,
-            index.sqrt_gamma, index.sg_scale, index.sg_zp,
-            q["qconst"][None], q["sqrt_delta"][None])[:, 0]
-        _, idx = jax.lax.top_k(-totals, k)
-        qb = (bounds.ub_components(_tuple_rows(index, idx[-1]), q)
-              + _qb_slack(index, idx, q["sqrt_delta"]))
-    else:
-        totals, comp_kth_fn = kernel_ops.bregman_ub_filter(
-            index.alpha, index.sqrt_gamma, q["qconst"], q["sqrt_delta"])
-        _, idx = jax.lax.top_k(-totals, k)
-        qb = comp_kth_fn(idx[-1])
-    return totals, idx, qb
-
-
-@functools.partial(jax.jit, static_argnames=("k", "budget"))
-def _knn_search_jit(index: BallForest, y: Array, k: int,
-                    budget: int) -> SearchResult:
-    """Exact kNN for one query (jit core, static budget)."""
-    q = _query_struct(index, y)
-
-    # ---- filter: total UB for every point (MXU matmul form) ----
-    totals, _idx, qb = _single_filter(index, q, k)     # (M,) Alg. 4 bounds
-
-    # ---- ball pruning + union (Theorem 3) ----
-    mask = _candidate_mask(index, q, qb)
-    num_candidates = jnp.sum(mask.astype(jnp.int32))
-
-    # ---- static-budget selection: all union members first, by UB ----
-    ids, dists = _refine(index, q, mask, totals, budget, k)
-    exact = num_candidates <= budget
-    return SearchResult(ids=ids, dists=dists, exact=exact,
-                        num_candidates=num_candidates)
-
-
-def knn_search(index, y: Array, k: int, budget: int,
-               validate: bool = True) -> SearchResult:
-    """Exact kNN for one query (static budget; accepts a mutable index)."""
-    if getattr(index, "is_tiered_store", False):
-        res = index.search(jnp.asarray(y, jnp.float32)[None, :], k, budget,
-                           validate=validate)
-        return SearchResult(ids=res.ids[0], dists=res.dists[0],
-                            exact=res.exact[0],
-                            num_candidates=res.num_candidates[0])
-    index = _as_forest(index, k)
-    budget = resolve_budget(budget, index.n, k)
-    if validate:
-        validate_queries(index.family, y)
-    return _knn_search_jit(index, y, k, budget)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "budget"))
-def _knn_search_approx_jit(
-    index: BallForest, y: Array, k: int, budget: int, p_guarantee: Array
-) -> SearchResult:
-    """Approximate kNN with probability guarantee p (paper §8, Prop. 1).
-
-    The Cauchy slack mu of the k-th bound is shrunk to c*mu with
-    ``c = Psi^-1(p*Psi(mu) + (1-p)*Psi(-kappa)) / mu`` where Psi is the
-    empirical CDF of the cross term beta_xy (index.beta_samples); each
-    subspace bound's sqrt term is scaled by c.  In the int8 tier the
-    quantization slack inflates ``qb`` BEFORE the shrink (matching the
-    batched and distributed paths), so the probabilistic guarantee holds
-    w.r.t. the decoded point set.
-    """
-    q = _query_struct(index, y)
-
-    totals, idx, qb = _single_filter(index, q, k)
-    kth = idx[-1]
-
-    # Full-space kappa and mu of the k-th bound (paper §8 notation).
-    sqrt_term = _tuple_rows(index, kth)["sqrt_gamma"] * q["sqrt_delta"]  # (M,)
-    kappa_i = qb - sqrt_term                           # per-subspace kappa
-    kappa = jnp.sum(kappa_i)
-    mu = jnp.sum(sqrt_term)
-
-    c = _cdf_shrink(index.beta_samples, mu, kappa, p_guarantee)
-    qb_approx = kappa_i + c * sqrt_term                # shrunk bounds
-
-    mask = _candidate_mask(index, q, qb_approx)
-    num_candidates = jnp.sum(mask.astype(jnp.int32))
-    ids, dists = _refine(index, q, mask, totals, budget, k)
-    return SearchResult(ids=ids, dists=dists, exact=num_candidates <= budget,
-                        num_candidates=num_candidates)
-
-
-def knn_search_approx(index, y: Array, k: int, budget: int,
-                      p_guarantee: Array,
-                      validate: bool = True) -> SearchResult:
-    """§8 approximate kNN for one query (accepts a mutable index)."""
-    if getattr(index, "is_tiered_store", False):
-        res = index.search(jnp.asarray(y, jnp.float32)[None, :], k, budget,
-                           p_guarantee=p_guarantee, validate=validate)
-        return SearchResult(ids=res.ids[0], dists=res.dists[0],
-                            exact=res.exact[0],
-                            num_candidates=res.num_candidates[0])
-    index = _as_forest(index, k)
-    budget = resolve_budget(budget, index.n, k)
-    validate_p_guarantee(p_guarantee)
-    if validate:
-        validate_queries(index.family, y)
-    return _knn_search_approx_jit(index, y, k, budget, p_guarantee)
-
-
 def _cdf_shrink(samples: Array, mu: Array, kappa: Array, p: Array) -> Array:
     """§8 Prop.-1 shrink factor c from the empirical beta_xy CDF.
 
-    Vectorized: ``mu``/``kappa`` may be scalars (single query) or (q,)
-    batches; returns the same shape.
+    Vectorized: ``mu``/``kappa`` are (q,) batches (or scalars); returns the
+    same shape.
     """
     s = samples.shape[0]
 
@@ -1284,11 +1125,8 @@ def knn_search_batch_reference(index, ys: Array, k: int, budget: int,
 
 
 # ---------------------------------------------------------------------------
-# Host wrappers (escape hatch: double the budget until the union fits)
+# Host wrappers: the budget ladder and the entry points that climb it
 # ---------------------------------------------------------------------------
-
-MAX_BUDGET_DOUBLINGS = 8
-
 
 def resolve_budget(budget, n: int, k: int) -> int:
     """THE refine-budget resolver: every public entry point routes its
@@ -1346,10 +1184,10 @@ def default_budget(index: BallForest, k: int) -> int:
 def fitted_budget_for_n(n: int, k: int, needed: int) -> int:
     """Smallest power-of-two budget (>= k, capped at ``n``) covering
     ``needed`` candidates.  The ONE sizing rule for overflow handling:
-    retries (single-host AND per-shard — dist/knn.py passes the shard
-    size as ``n``) and serving-side pinned budgets all use it, so they
-    land on the same static shapes and reuse each other's compiled
-    programs.
+    :func:`run_ladder`'s retries (single-host AND per-shard — dist/knn.py
+    passes the shard size as ``n``) and serving-side pinned budgets all
+    use it, so they land on the same static shapes and reuse each other's
+    compiled programs.
     """
     need = max(int(needed), k, 1)
     return int(min(n, 1 << (need - 1).bit_length()))
@@ -1360,67 +1198,81 @@ def fitted_budget(index: BallForest, k: int, needed: int) -> int:
     return fitted_budget_for_n(index.n, k, needed)
 
 
+def run_ladder(launch, n: int, k: int, budget: int, *, stop_retry=None):
+    """THE budget ladder: launch at ``budget`` and grow it until the batch
+    is exact.
+
+    ``launch(budget, attempt)`` runs one search at that budget and returns
+    ``(result, need)``: its :class:`SearchResult` and the largest Theorem-3
+    union (over its rows, or over a sharded launch's shards) the next
+    budget has to hold.  The ladder stops when every row is exact or the
+    budget has reached ``n``; otherwise it stops if ``stop_retry`` (no-arg
+    callable -> bool) says so, handing back the budget-capped partial
+    result (overflowed rows keep ``exact=False``); otherwise it launches
+    again at :func:`fitted_budget_for_n` of ``need``.  ``num_candidates``
+    does not depend on the budget, so that second launch holds every union
+    and the ladder ends within two launches.  The ladder opens no spans
+    and keeps no records: each caller's ``launch`` does.
+
+    Returns ``(result, budget, retries, stopped)``: the last launch's
+    result and budget, the launches after the first, and whether
+    ``stop_retry`` ended the ladder.
+    """
+    attempt = 0
+    while True:
+        res, need = launch(budget, attempt)
+        if bool(np.asarray(res.exact).all()) or budget >= n:
+            return res, budget, attempt, False
+        if stop_retry is not None and stop_retry():
+            return res, budget, attempt, True
+        # need > budget on overflow, so the fitted budget strictly grows.
+        budget = fitted_budget_for_n(n, k, need)
+        attempt += 1
+
+
 def knn(index: BallForest, y, k: int, budget: int | None = None,
         approx_p: float | None = None) -> SearchResult:
-    """Host-level kNN: retries with doubled budget when the union overflows.
+    """kNN for one (d,) query: the q = 1 row of :func:`knn_batch`.
 
     Always exact when ``approx_p is None``; with ``approx_p`` the result has
-    the paper's probability guarantee instead.
+    the paper's probability guarantee instead.  Fields come back without
+    the batch axis.
     """
-    index = _as_forest(index, k)
-    y = jnp.asarray(y, jnp.float32)
-    validate_queries(index.family, y)
-    validate_p_guarantee(approx_p)
-    budget = resolve_budget(budget, index.n, k)
-    while True:
-        if approx_p is None:
-            res = knn_search(index, y, k, budget, validate=False)
-        else:
-            res = knn_search_approx(index, y, k, budget,
-                                    jnp.float32(approx_p), validate=False)
-        if bool(res.exact) or budget >= index.n:
-            return res
-        budget = min(index.n, budget * 2)
+    res = knn_batch(index, jnp.asarray(y, jnp.float32)[None], k, budget,
+                    approx_p)
+    return jax.tree.map(lambda f: f[0], res)
 
 
 def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
               approx_p: float | None = None, *,
               target_recall: float | None = None,
-              max_doublings: int = MAX_BUDGET_DOUBLINGS,
               block_rows: int | None = None,
               stop_retry=None, return_stats: bool = False,
               validate: bool = True):
     """Batched kNN via the fused :func:`knn_search_batch` pipeline.
 
-    One retry policy for the whole batch: if ANY query's Theorem-3 union
-    overflows, the block re-runs with a budget sized to the largest
+    One :func:`run_ladder` for the whole batch: if ANY query's Theorem-3
+    union overflows, the block re-runs with a budget sized to the largest
     observed union (``num_candidates`` is budget-independent, so one retry
-    normally resolves the overflow), rounded up to a power of two so
-    repeated budgets reuse compiled programs.  The loop is bounded by
-    ``max_doublings``; if exhausted, a warning is logged and the block
-    falls back to ONE fused brute-force scan (exact by construction, no
-    per-query dataset gather), preserving the invariant that exact-mode
-    results are exact and approx-mode results carry the §8 guarantee.
+    resolves the overflow), rounded up to a power of two so repeated
+    budgets reuse compiled programs.  Exact-mode results are exact and
+    approx-mode results carry the §8 guarantee.
 
     ``block_rows`` tunes the streaming scans' block size (peak memory vs
     scan overhead — :func:`resolve_block_rows`); it is forwarded to every
     retry, so one setting governs the whole call.
 
     **Deadline-capped ladder**: ``stop_retry`` (no-arg callable -> bool) is
-    consulted before every ADDITIONAL launch — each budget-growth retry
-    and the final scan escalation.  Returning True ends the ladder
-    immediately with the best result so far (rows whose union overflowed
-    keep ``exact=False`` — a budget-capped PARTIAL result), instead of
-    doubling forever past a deadline.  serve/retrieval.py passes
-    ``lambda: clock() + est_launch > deadline`` here; the default ``None``
-    preserves the always-exact contract.
+    consulted before every ADDITIONAL launch.  Returning True ends the
+    ladder immediately with the best result so far (rows whose union
+    overflowed keep ``exact=False`` — a budget-capped PARTIAL result).
+    The default ``None`` preserves the always-exact contract.
 
     ``return_stats=True`` returns ``(SearchResult, BatchStats)`` — the
-    structured escalation counters services and benchmarks alert on
-    (the log line is advisory only) and one record per launch.  Each
-    launch runs inside a ``knn.launch`` profiler span (``budget``,
-    ``attempt``) around ``knn.dispatch`` and ``knn.wait``, as the
-    service's ``svc.launch``.
+    structured escalation counters services and benchmarks alert on and
+    one record per launch.  Each launch runs inside a ``knn.launch``
+    profiler span (``budget``, ``attempt``) around ``knn.dispatch`` and
+    ``knn.wait``, as the service's ``svc.launch``.
 
     ``target_recall`` (mutually exclusive with ``approx_p``) selects the
     approximate mode at a CALIBRATED shrink: the index's fitted recall
@@ -1441,22 +1293,21 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
         validate_queries(index.family, ys)
     budget = resolve_budget(budget, index.n, k)
     p = None if approx_p is None else jnp.float32(approx_p)
-
-    def run(b):
-        if p is None:
-            return knn_search_batch(index, ys, k, b, block_rows,
-                                    validate=False)
-        return knn_search_batch_approx(index, ys, k, b, p, block_rows,
-                                       validate=False)
-
+    # A tiered store refines by its own gather (core/tiered.py).
+    tiered = getattr(index, "is_tiered_store", False)
     launches = []
 
-    def launch(b, attempt, thunk, dense):
+    def launch(b, attempt):
         with jax.profiler.TraceAnnotation("knn.launch", budget=b,
                                           attempt=attempt):
             with jax.profiler.TraceAnnotation("knn.dispatch"):
                 t1 = time.perf_counter()
-                res = thunk()
+                if p is None:
+                    res = knn_search_batch(index, ys, k, b, block_rows,
+                                           validate=False)
+                else:
+                    res = knn_search_batch_approx(index, ys, k, b, p,
+                                                  block_rows, validate=False)
             with jax.profiler.TraceAnnotation("knn.wait"):
                 t2 = time.perf_counter()
                 jax.block_until_ready(res)
@@ -1464,75 +1315,16 @@ def knn_batch(index: BallForest, ys, k: int, budget: int | None = None,
             launches.append({
                 "budget": int(b),
                 "num_candidates": int(np.asarray(res.num_candidates).max()),
-                "dense": dense, "dispatch_s": t2 - t1, "wait_s": t3 - t2})
+                "dense": not tiered and dense_refine(ys.shape[0], b, index.n),
+                "dispatch_s": t2 - t1, "wait_s": t3 - t2})
+        return res, launches[-1]["num_candidates"]
+
+    res, budget, escalations, stopped = run_ladder(
+        launch, index.n, k, budget, stop_retry=stop_retry)
+    if not return_stats:
         return res
-
-    def done(res, escalations, scan=False, stopped=False):
-        stats = BatchStats(escalations=escalations, budget_final=budget,
-                           escalated_to_scan=scan, stopped_early=stopped,
-                           launches=tuple(launches))
-        return (res, stats) if return_stats else res
-
-    for attempt in range(max_doublings + 1):
-        # A tiered store refines by its own gather (core/tiered.py).
-        dense = (not getattr(index, "is_tiered_store", False)
-                 and dense_refine(ys.shape[0], budget, index.n))
-        res = launch(budget, attempt, lambda: run(budget), dense)
-        if bool(np.asarray(res.exact).all()) or budget >= index.n:
-            return done(res, attempt)
-        if attempt == max_doublings:
-            break
-        if stop_retry is not None and stop_retry():
-            # Deadline exhausted: hand back the budget-capped partial
-            # result (overflowed rows keep exact=False) instead of
-            # launching again.
-            return done(res, attempt, stopped=True)
-        # needed > budget on overflow, so the fitted budget strictly grows.
-        budget = fitted_budget(index, k, launches[-1]["num_candidates"])
-    escalations = max_doublings
-    if stop_retry is not None and stop_retry():
-        return done(res, escalations, stopped=True)
-    logger.warning(
-        "knn_batch: budget cap exhausted after %d doublings (budget=%d, "
-        "%d/%d queries overflowed); escalating to a full linear scan "
-        "(n=%d)", max_doublings, budget,
-        int(jnp.sum(~res.exact)), ys.shape[0], index.n)
-    # Full scan instead of run(index.n): a budget=n refine would gather a
-    # (q, n, d) copy of the dataset; the fused brute-force distance needs
-    # no per-query row gather.  num_candidates (budget-independent) comes
-    # from the last capped run.  A tiered store pays one full
-    # materialization here — the escalation is already the worst case.
-    scan_index = (index.as_resident_forest()
-                  if getattr(index, "is_tiered_store", False) else index)
-    capped = res
-
-    def scan():
-        ids, dists = _brute_force_live(scan_index, ys, k)
-        return SearchResult(ids=ids, dists=dists,
-                            exact=jnp.ones(ys.shape[0], bool),
-                            num_candidates=capped.num_candidates)
-
-    res = launch(index.n, max_doublings + 1, scan, False)
-    return done(res, escalations, scan=True)
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _brute_force_live(index: BallForest, ys: Array, k: int):
-    """Linear scan over the LIVE rows of an index — the escalation oracle.
-
-    Unlike :func:`brute_force_knn` over ``index.data``, this masks
-    tombstoned/padded rows (``point_ids < 0``, whose data is the inert
-    ones-fill at a finite distance) so a mutated index never surfaces a
-    deleted id even on the budget-cap escape hatch.  ``rows_view`` decodes
-    the int8 tier, so the scan is exact over the stored point set there
-    too.
-    """
-    fam = index.family
-    rows = index.rows_view()
-    dist = jax.vmap(lambda y: fam.distance(rows, y[None, :]))(ys)
-    dist = jnp.where((index.point_ids >= 0)[None, :], dist, POS_BIG)
-    neg, idx = jax.lax.top_k(-dist, k)                  # (q, k)
-    return jnp.take(index.point_ids, idx), -neg
+    return res, BatchStats(escalations=escalations, budget_final=budget,
+                           stopped_early=stopped, launches=tuple(launches))
 
 
 def brute_force_knn(data, y, k: int, family) -> tuple[Array, Array]:

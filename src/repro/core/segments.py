@@ -22,9 +22,9 @@ O(n * d * M) rebuild.  The classic LSM answer, adapted to the BB-forest:
 * **Tombstones** — :meth:`SegmentedForest.delete` overwrites a point's row
   with the search-inert fill (``index.tombstone_rows``): filter stats
   beyond any finite top-k, corner stats that fail every admission, id -1.
-  The filter, Theorem-3 prune, and refine phases of all three search
-  paths (``knn_search``, ``knn_search_batch``, ``dist.distributed_knn``)
-  skip deleted rows without knowing deletions exist.
+  The filter, Theorem-3 prune, and refine phases of both search paths
+  (``knn_search_batch`` and ``dist.distributed_knn``) skip deleted rows
+  without knowing deletions exist.
 * **Compaction** — :meth:`SegmentedForest.compact` re-seals everything
   into one main segment, either by a cheap **merge** (drop dead rows,
   re-sort the shared layout, recompute corner tables with

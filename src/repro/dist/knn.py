@@ -55,12 +55,11 @@ from repro.core.calibrate import resolve_p_guarantee
 from repro.core.index import (BallForest, REPLICATED_FIELDS, pad_points,
                               point_fields, refresh_envelopes)
 from repro.core.quantize import ub_slack
-from repro.core.search import (MAX_BUDGET_DOUBLINGS,
-                               SearchResult, _batch_filter_topk,
+from repro.core.search import (SearchResult, _batch_filter_topk,
                                _cdf_shrink, _refine_batch,
                                _stream_prune_compact, _tuple_rows,
-                               dense_refine, fitted_budget_for_n,
-                               resolve_block_rows, resolve_budget,
+                               dense_refine, resolve_block_rows,
+                               resolve_budget, run_ladder,
                                validate_p_guarantee)
 from repro.core.transform import Partition, q_transform_views
 from . import sharding as shd
@@ -275,7 +274,6 @@ def distributed_knn(sharded: ShardedForest, queries, *, family: str, k: int,
                     approx_p: float | None = None,
                     target_recall: float | None = None,
                     block_rows: int | None = None,
-                    max_doublings: int = MAX_BUDGET_DOUBLINGS,
                     launch_timeout_s: float | None = None,
                     launch_hook=None, stop_retry=None,
                     clock=time.monotonic) -> SearchResult:
@@ -287,9 +285,10 @@ def distributed_knn(sharded: ShardedForest, queries, *, family: str, k: int,
     single-host pipeline (``core.search.resolve_block_rows``).
     Returns the usual ``(ids, dists, exact, num_candidates)`` with
     ``num_candidates`` the global Theorem-3 union size per query.  On
-    overflow the whole block retries with a budget fitted to the largest
-    per-shard union (same power-of-two rule as the single-host wrapper);
-    the loop ends at ``budget == local_n`` where the union always fits, so
+    overflow the whole block retries through ``core.search.run_ladder``
+    with ``n = local_n``: the next budget is fitted to the largest
+    per-shard union, and the ladder ends exact within two launches (at
+    worst at ``budget == local_n``, where the union always fits), so
     exact mode stays exact and ``exact`` is always truthful.
 
     **Robustness wiring** (serve/retrieval.py): every retry is its own
@@ -337,7 +336,7 @@ def distributed_knn(sharded: ShardedForest, queries, *, family: str, k: int,
             for f in point_fields(forest) + REPLICATED_FIELDS}
     extra = () if approx_p is None else (jnp.float32(approx_p),)
 
-    for attempt in range(max_doublings + 1):
+    def launch(b, attempt):
         prog = _dist_knn_program(mesh, sharded.axis, forest.family_name,
                                  forest.partition, forest.num_clusters,
                                  forest.storage, k, b,
@@ -355,9 +354,7 @@ def distributed_knn(sharded: ShardedForest, queries, *, family: str, k: int,
                 f"distributed_knn launch (budget={b}, attempt={attempt}) "
                 f"blocked {elapsed:.3f}s > launch_timeout_s="
                 f"{launch_timeout_s:.3f}s", result=res, elapsed_s=elapsed)
-        if bool(jnp.all(exact)) or b >= local_n or attempt == max_doublings:
-            break
-        if stop_retry is not None and stop_retry():
-            break
-        b = fitted_budget_for_n(local_n, k, int(jnp.max(need)))
+        return res, int(jnp.max(need))
+
+    res, *_ = run_ladder(launch, local_n, k, b, stop_retry=stop_retry)
     return res

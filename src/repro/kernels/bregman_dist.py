@@ -57,7 +57,7 @@ def bregman_refine(
     """Exact D_f(rows[i], y) -> (b,): the q=1 slice of the batch kernel.
 
     Delegating keeps ONE kernel body (accumulation, family-specific safe
-    padding) serving both the single-query and batched search paths.
+    padding) serving both entry points.
     """
     return bregman_refine_batch(
         rows[None], grad[None], c_y[None], family,

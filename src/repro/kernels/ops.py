@@ -1,9 +1,9 @@
 """Dispatching jit wrappers around the Pallas kernels.
 
-Backend policy (per DESIGN.md): on TPU the compiled Pallas kernels run; on
-CPU (this container) the pure-jnp references run by default so that jitted
-programs (including the 512-device dry-run) lower through stock XLA, and
-``impl='interpret'`` forces the Pallas interpreter for kernel validation.
+Backend policy: on TPU the compiled Pallas kernels run; on CPU the
+pure-jnp references run by default so that jitted programs (including the
+512-device dry-run) lower through stock XLA, and ``impl='interpret'``
+forces the Pallas interpreter for kernel validation.
 
 Set env ``REPRO_KERNEL_IMPL`` to 'pallas' | 'interpret' | 'ref' to override.
 """
@@ -36,37 +36,6 @@ def _impl(override: str | None = None) -> str:
 # ---------------------------------------------------------------------------
 # BrePartition filter + refine
 # ---------------------------------------------------------------------------
-
-def bregman_ub_filter(alpha, sqrt_gamma, qconst, sqrt_delta, impl=None):
-    """Total UBs for one query + a closure for the Alg.-4 kth components.
-
-    Returns (totals (n,), comp_of(kth) -> (M,)).  Strictly single-query:
-    ``qconst``/``sqrt_delta`` must be (M,).  A (q, M) batch must go through
-    :func:`bregman_ub_matrix` — this used to fall back to the jnp reference
-    silently, hiding the Pallas kernel from batch callers.
-    """
-    if qconst.ndim != 1 or sqrt_delta.ndim != 1:
-        raise ValueError(
-            "bregman_ub_filter is single-query: qconst/sqrt_delta must be "
-            f"(M,), got {qconst.shape}/{sqrt_delta.shape}; use "
-            "bregman_ub_matrix for query batches")
-    mode = _impl(impl)
-    if mode == "ref":
-        totals = ref.bregman_ub_totals(alpha, sqrt_gamma, qconst, sqrt_delta)
-    else:
-        qsum = jnp.sum(qconst)[None]
-        totals = _ub.bregman_ub_matrix(
-            alpha, sqrt_gamma, qsum, sqrt_delta[None, :],
-            interpret=(mode == "interpret"),
-        )[:, 0]
-
-    def comp_of(kth):
-        a = jnp.take(alpha, kth, axis=0)
-        sg = jnp.take(sqrt_gamma, kth, axis=0)
-        return a + qconst + sg * sqrt_delta
-
-    return totals, comp_of
-
 
 def bregman_ub_matrix(alpha, sqrt_gamma, qconst, sqrt_delta, impl=None):
     """(n, q) UB totals for a query batch."""

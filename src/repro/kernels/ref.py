@@ -17,13 +17,6 @@ from repro.core import quantize as qz
 Array = jax.Array
 
 
-def bregman_ub_totals(alpha: Array, sqrt_gamma: Array, qconst: Array,
-                      sqrt_delta: Array) -> Array:
-    """Total UB per point for a single query.  (n, M),(n, M),(M,),(M,)->(n,)."""
-    return (jnp.sum(alpha, -1) + jnp.sum(qconst, -1)
-            + jnp.dot(sqrt_gamma, sqrt_delta, precision=F32_PRECISION))
-
-
 def bregman_ub_matrix(alpha: Array, sqrt_gamma: Array, qconst: Array,
                       sqrt_delta: Array) -> Array:
     """UB totals for a query batch.  (n,M),(n,M),(q,M),(q,M) -> (n,q)."""

@@ -237,11 +237,9 @@ class KNNLMHook:
     service_tenant: str = "knnlm"
     deadline_s: float | None = None
     queries_served: int = 0
-    # Structured budget-retry telemetry (replaces grepping logs): total
-    # budget escalations taken, full linear-scan fallbacks, and the budget
-    # the most recent launch actually ran with.
+    # Structured budget-retry telemetry: total budget escalations taken
+    # and the budget the most recent launch actually ran with.
     escalations: int = 0
-    scan_fallbacks: int = 0
     budget_final: int = 0
     # next_tokens cached on device (lazy, refreshed when the store mutates)
     _next_dev: Array | None = dataclasses.field(
@@ -307,7 +305,7 @@ class KNNLMHook:
             # budget keeps the refine shape stable; the batch axis still
             # varies with the live-slot count (bounded by the engine's slot
             # pool, so the jit cache holds at most `slots` programs per k).
-            # Rare union overflows fall back to the capped sized retry.
+            # Rare union overflows take the ladder's sized retry.
             res, stats = bp_search.knn_batch(
                 self.store.search_index(), h, self.k, budget=self.budget,
                 approx_p=self.approx_p, target_recall=self.target_recall,
@@ -315,7 +313,6 @@ class KNNLMHook:
                 return_stats=True)
             self.queries_served += int(h.shape[0])
             self.escalations += stats.escalations
-            self.scan_fallbacks += int(stats.escalated_to_scan)
             self.budget_final = stats.budget_final
             # Grow-only budget adaptation: only when this step's unions
             # outgrew the effective budget (no pin is installed while the
@@ -336,10 +333,10 @@ class KNNLMHook:
                 fitted = bp_search.fitted_budget(self.store.index, self.k,
                                                  needed)
                 self.budget = max(current, min(fitted, cap))  # never shrink
-            # Defense in depth: knn_batch escalates to a full refine on cap
-            # exhaustion so inexact rows shouldn't occur, but if one ever
-            # does its neighbors are an arbitrary union prefix — serve the
-            # pure LM distribution for it instead of a biased mixture.
+            # Defense in depth: knn_batch's ladder ends exact, so inexact
+            # rows shouldn't occur, but if one ever does its neighbors are
+            # an arbitrary union prefix — serve the pure LM distribution
+            # for it instead of a biased mixture.
             ids, dists, use = res.ids, res.dists, res.exact
         # Upload the value table once per store version, not per tick; a
         # grow/evict bumps store.version and forces a re-upload so appended

@@ -26,9 +26,9 @@ containment.  Robustness is the CONTRACT here, not a best effort:
   The ladder is COST-DRIVEN: a per-tenant launch-cost model (peak-tracking
   EWMA of observed launch seconds) prices each tier, and the microbatch
   enters at the highest tier whose price fits the remaining deadline.
-  Exact-tier budget retries reuse ``fitted_budget`` but are capped by the
-  remaining deadline instead of doubling forever; when time runs out the
-  last capped result is returned as-is.  Every response carries a
+  Exact- and approx-tier budget retries climb ``core.search.run_ladder``,
+  capped by the remaining deadline; when time runs out the last capped
+  result is returned as-is.  Every response carries a
   ``quality`` label (``exact | approx | partial | shed``) derived from
   what ACTUALLY happened — the per-row ``exact`` flags and the pipeline
   that ran — never from what was planned, so degradation is observable
@@ -874,11 +874,11 @@ class RetrievalService:
     def _run_tier(self, tenant: Tenant, snapshot, ys, k: int, tier: str,
                   p: float, deadline: float, mb: dict, q: int):
         """Run one ladder tier to completion; returns (result, used_approx,
-        budget).  Budget retries inside the exact/approx tiers reuse the
-        ``fitted_budget`` machinery but stop when the NEXT launch would
-        not fit the remaining deadline — the budget-capped partial path.
-        Each launch is recorded in the microbatch's ``mb``; ``q`` is its
-        real (unpadded) query rows.
+        budget).  The exact/approx tiers climb ``core.search.run_ladder``,
+        which stops when the NEXT launch would not fit the remaining
+        deadline — the budget-capped partial path.  Each launch is
+        recorded in the microbatch's ``mb``; ``q`` is its real (unpadded)
+        query rows.
         """
         cfg = self.config
         approx = tier == QUALITY_APPROX
@@ -921,30 +921,26 @@ class RetrievalService:
                                             validate=False))
             return res, False, budget
 
-        budget = bp.default_budget(snapshot, k)
-        while True:
-            b = budget
+        def search(b: int):
             if approx:
-                res = self._launch(
-                    tenant, tier, b, mb, q, dense(b),
-                    lambda: bp.knn_search_batch_approx(
-                        snapshot, ys, k, b, np.float32(p),
-                        block_rows=tenant.block_rows, validate=False))
-            else:
-                res = self._launch(
-                    tenant, tier, b, mb, q, dense(b),
-                    lambda: bp.knn_search_batch(snapshot, ys, k, b,
-                                                block_rows=tenant.block_rows,
-                                                validate=False))
-            if bool(np.asarray(res.exact).all()) or budget >= snapshot.n:
-                return res, approx, budget
-            if stop_retry():
-                # Deadline-capped: keep the partial result instead of
-                # doubling forever (the rows that fit are still exact).
-                return res, approx, budget
-            self.counters["escalations"] += 1
-            budget = bp.fitted_budget(
-                snapshot, k, int(np.asarray(res.num_candidates).max()))
+                return bp.knn_search_batch_approx(
+                    snapshot, ys, k, b, np.float32(p),
+                    block_rows=tenant.block_rows, validate=False)
+            return bp.knn_search_batch(snapshot, ys, k, b,
+                                       block_rows=tenant.block_rows,
+                                       validate=False)
+
+        def launch(b: int, attempt: int):
+            if attempt:
+                self.counters["escalations"] += 1
+            res = self._launch(tenant, tier, b, mb, q, dense(b),
+                               lambda: search(b))
+            return res, int(np.asarray(res.num_candidates).max())
+
+        res, budget, _, _ = bp.run_ladder(
+            launch, snapshot.n, k, bp.default_budget(snapshot, k),
+            stop_retry=stop_retry)
+        return res, approx, budget
 
     def _launch(self, tenant: Tenant, tier: str, budget: int, mb: dict,
                 q: int, dense: bool, thunk):

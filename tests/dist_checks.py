@@ -102,7 +102,7 @@ def check_sharded_shared_pass():
                     del shared_calls[:]
                     runs[path] = dknn.distributed_knn(
                         sharded, yv, family=family, k=k, budget=budget,
-                        mesh=mesh, max_doublings=0)
+                        mesh=mesh, stop_retry=lambda: True)
                     dknn.dense_refine = search.dense_refine
                     assert bool(shared_calls) == (path == "rule" and dense), (
                         impl, storage, budget, path)
@@ -129,6 +129,55 @@ def check_sharded_shared_pass():
             print(f"  sharded shared pass ok ({impl}, {storage})")
     search._shared_dists = shared_dists
     os.environ.pop("REPRO_KERNEL_IMPL")
+
+
+def check_distributed_ladder():
+    """``distributed_knn`` climbs the one ladder (core/search.run_ladder)
+    with n = local_n: from an overflowing per-shard budget it ends exact
+    within two launches, at the budget fitted to the first launch's
+    largest per-shard union, with the ids a single launch at that budget
+    returns and the single-host ladder's distances."""
+    mesh = make_mesh((8,), ("data",))
+    family = "itakura_saito"
+    fam = get_family(family)
+    n, d, q, k = 1024, 16, 4, 6
+    data = np.asarray(fam.sample(jax.random.PRNGKey(0), (n, d)))
+    queries = jnp.asarray(fam.sample(jax.random.PRNGKey(1), (q, d)))
+    forest = build_index(data, family, m=4, num_clusters=16, seed=0)
+    sharded = dknn.shard_index(forest, mesh)
+    local_n = sharded.local_n
+    yv = dknn.query_subview(forest.partition, queries)
+    seen, hooked = [], []
+    real = dknn.run_ladder
+
+    def spy(launch, n_rows, k_, budget, **kw):
+        assert n_rows == local_n
+        def logged(b, attempt):
+            res, need = launch(b, attempt)
+            seen.append((b, need))
+            return res, need
+        return real(logged, n_rows, k_, budget, **kw)
+
+    dknn.run_ladder = spy
+    try:
+        res = dknn.distributed_knn(sharded, yv, family=family, k=k, budget=k,
+                                   mesh=mesh, launch_hook=hooked.append)
+    finally:
+        dknn.run_ladder = real
+    assert len(seen) == len(hooked) == 2, seen
+    (b0, need0), (b1, need1) = seen
+    assert b0 == k < need0 == need1 <= b1
+    assert b1 == search.fitted_budget_for_n(local_n, k, need0)
+    assert bool(jnp.all(res.exact))
+    one = dknn.distributed_knn(sharded, yv, family=family, k=k, budget=b1,
+                               mesh=mesh, stop_retry=lambda: True)
+    np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(one.ids))
+    ref = search.knn_batch(forest, queries, k, budget=k)
+    np.testing.assert_allclose(np.sort(np.asarray(res.dists), axis=1),
+                               np.sort(np.asarray(ref.dists), axis=1),
+                               rtol=1e-5, atol=1e-5)
+    print(f"  distributed ladder ok (budgets {b0} -> {b1}, "
+          f"local_n={local_n})")
 
 
 def check_collective_matmul():

@@ -1,9 +1,8 @@
 """Batched pipeline parity: knn_search_batch vs per-query vs brute force.
 
 Covers all five Bregman families, exact and approximate modes, the
-streaming k-selection (multi-block) path, the capped budget-doubling
-retry, the batched refine kernel, and the ub_filter dispatch regression
-(no silent ref fallback).
+streaming k-selection (multi-block) path, the budget ladder, and the
+batched refine kernel.
 """
 
 import logging
@@ -17,7 +16,6 @@ from repro.core.bregman import get_family, family_names
 from repro.core.index import build_index
 from repro.core import search
 from repro.kernels import ops, ref
-from repro.kernels import bregman_ub as _ub
 from repro.kernels.bregman_dist import bregman_refine_batch
 
 
@@ -95,15 +93,19 @@ def test_batch_budget_retry_path():
                                rtol=2e-3, atol=2e-3)
 
 
-def test_batch_retry_cap_escalates_to_full_refine(caplog):
-    """Exhausting the doubling cap logs a warning and escalates to budget=n,
-    so exact-mode results stay exact (the pre-batch invariant)."""
+def test_batch_retry_cap_escalates_to_full_refine():
+    """A budget of 8 overflows, and the ladder's one sized retry ends
+    exact: num_candidates does not depend on the budget, so the second
+    launch holds every union."""
     data, queries, fam = _dataset("squared_euclidean", n=400)
     index = build_index(data, "squared_euclidean", m=4, num_clusters=8, seed=0)
-    with caplog.at_level(logging.WARNING, logger="repro.core.search"):
-        res = search.knn_batch(index, queries, 5, budget=8, max_doublings=0)
-    assert any("budget cap exhausted" in r.message for r in caplog.records)
+    res, stats = search.knn_batch(index, queries, 5, budget=8,
+                                  return_stats=True)
     assert bool(jnp.all(res.exact))
+    assert len(stats.launches) == 2 and stats.escalations == 1
+    assert stats.launches[0]["num_candidates"] > 8
+    assert stats.budget_final == search.fitted_budget(
+        index, 5, stats.launches[0]["num_candidates"])
     _, bf_dists = search.brute_force_knn(data, queries, 5, fam)
     np.testing.assert_allclose(np.sort(np.asarray(res.dists), axis=1),
                                np.sort(np.asarray(bf_dists), axis=1),
@@ -193,41 +195,6 @@ def test_brute_force_batched_matches_per_query():
 # ---------------------------------------------------------------------------
 # kernel dispatch regressions
 # ---------------------------------------------------------------------------
-
-def test_ub_filter_single_query_uses_pallas_path(monkeypatch):
-    """Regression: single-query shape must hit the kernel, not silently fall
-    back to the jnp reference (the old ``qconst.ndim != 1`` guard)."""
-    rng = np.random.default_rng(0)
-    alpha = jnp.asarray(rng.normal(size=(64, 8)), jnp.float32)
-    sg = jnp.asarray(np.abs(rng.normal(size=(64, 8))), jnp.float32)
-    qc = jnp.asarray(rng.normal(size=(8,)), jnp.float32)
-    sd = jnp.asarray(np.abs(rng.normal(size=(8,))), jnp.float32)
-
-    calls = []
-    real = _ub.bregman_ub_matrix
-    monkeypatch.setattr(
-        ops._ub, "bregman_ub_matrix",
-        lambda *a, **kw: calls.append(1) or real(*a, **kw))
-    totals, comp_of = ops.bregman_ub_filter(alpha, sg, qc, sd,
-                                            impl="interpret")
-    assert calls, "interpret impl bypassed the Pallas kernel"
-    want = ref.bregman_ub_totals(alpha, sg, qc, sd)
-    np.testing.assert_allclose(np.asarray(totals), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(comp_of(3)),
-                               np.asarray(alpha[3] + qc + sg[3] * sd),
-                               rtol=1e-5)
-
-
-def test_ub_filter_rejects_query_batch():
-    rng = np.random.default_rng(0)
-    alpha = jnp.asarray(rng.normal(size=(32, 4)), jnp.float32)
-    sg = jnp.abs(alpha)
-    qc = jnp.zeros((2, 4), jnp.float32)
-    sd = jnp.ones((2, 4), jnp.float32)
-    with pytest.raises(ValueError, match="bregman_ub_matrix"):
-        ops.bregman_ub_filter(alpha, sg, qc, sd)
-
 
 @pytest.mark.parametrize("family", family_names())
 def test_batched_refine_kernel_matches_ref(family):
@@ -334,17 +301,18 @@ def _knn_spans(trace_dir) -> list:
     return sorted(out)
 
 
-@pytest.mark.parametrize("max_doublings", [8, 0], ids=["retry", "scan"])
-def test_knn_batch_one_span_and_record_per_launch(max_doublings, tmp_path):
-    """Each launch of knn_batch's ladder, the overflowing first budget, its
-    retry or the escalated scan, opens one ``knn.launch`` span around
-    ``knn.dispatch`` and ``knn.wait`` and leaves one BatchStats record."""
+@pytest.mark.parametrize("stopped", [False, True], ids=["retry", "stopped"])
+def test_knn_batch_one_span_and_record_per_launch(stopped, tmp_path):
+    """Each launch of knn_batch's ladder, the overflowing first budget and
+    its retry, opens one ``knn.launch`` span around ``knn.dispatch`` and
+    ``knn.wait`` and leaves one BatchStats record; a ``stop_retry`` that
+    ends the ladder leaves the one launch it made."""
     data, queries, _ = _dataset("squared_euclidean", n=400)
     index = build_index(data, "squared_euclidean", m=4, num_clusters=8, seed=0)
 
     def call():
         return search.knn_batch(index, queries, 5, budget=8,
-                                max_doublings=max_doublings,
+                                stop_retry=(lambda: True) if stopped else None,
                                 return_stats=True)
 
     call()                                      # compile outside the profile
@@ -353,14 +321,12 @@ def test_knn_batch_one_span_and_record_per_launch(max_doublings, tmp_path):
         res, stats = call()
     finally:
         jax.profiler.stop_trace()
-    assert bool(jnp.all(res.exact))
-    assert stats.escalated_to_scan == (max_doublings == 0)
+    assert bool(jnp.all(res.exact)) != stopped
+    assert stats.stopped_early == stopped
     records = stats.launches
-    assert len(records) == stats.escalations + 1 + int(
-        stats.escalated_to_scan) >= 2
+    assert len(records) == stats.escalations + 1 == (1 if stopped else 2)
     assert records[0]["budget"] == 8
-    assert records[-1]["budget"] == (index.n if stats.escalated_to_scan
-                                     else stats.budget_final)
+    assert records[-1]["budget"] == stats.budget_final
     for r in records:
         assert set(r) == {"budget", "num_candidates", "dense", "dispatch_s",
                           "wait_s"}

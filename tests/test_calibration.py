@@ -214,7 +214,7 @@ def test_dist_1x1_parity_with_target_recall():
     assert sharded.forest.calibration is not None   # survives sharding
     yv = dknn.query_subview(forest.partition, jnp.asarray(qs))
     res = dknn.distributed_knn(sharded, yv, family="itakura_saito", k=K,
-                               budget=N, mesh=mesh, max_doublings=0,
+                               budget=N, mesh=mesh, stop_retry=lambda: True,
                                target_recall=0.9)
     ref = search.knn_search_batch_approx(forest, jnp.asarray(qs), K, N,
                                          target_recall=0.9)

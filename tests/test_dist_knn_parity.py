@@ -54,7 +54,8 @@ def test_exact_mode_bit_identical(mesh, family):
     yv = dknn.query_subview(forest.partition, queries)
     for budget in (N, N // 2):
         res = dknn.distributed_knn(sharded, yv, family=family, k=K,
-                                   budget=budget, mesh=mesh, max_doublings=0)
+                                   budget=budget, mesh=mesh,
+                                   stop_retry=lambda: True)
         ref = search.knn_search_batch(forest, queries, K, budget)
         _assert_bitwise(res, ref)
 
@@ -65,7 +66,8 @@ def test_approx_mode_bit_identical(mesh, family):
     sharded = dknn.shard_index(forest, mesh)
     yv = dknn.query_subview(forest.partition, queries)
     res = dknn.distributed_knn(sharded, yv, family=family, k=K, budget=N,
-                               mesh=mesh, approx_p=0.9, max_doublings=0)
+                               mesh=mesh, approx_p=0.9,
+                               stop_retry=lambda: True)
     ref = search.knn_search_batch_approx(forest, queries, K, N,
                                          jnp.float32(0.9))
     _assert_bitwise(res, ref)
@@ -81,7 +83,7 @@ def test_budget_overflow_retry_keeps_exact_truthful(mesh):
     yv = dknn.query_subview(forest.partition, queries)
 
     capped = dknn.distributed_knn(sharded, yv, family=family, k=K, budget=K,
-                                  mesh=mesh, max_doublings=0)
+                                  mesh=mesh, stop_retry=lambda: True)
     assert not bool(jnp.all(capped.exact)), \
         "test needs an overflowing budget; shrink it"
     # truthful under the cap: the overflowing rows are flagged, not faked

@@ -37,3 +37,10 @@ def test_sharded_shared_pass_subprocess():
     """Four shards, the refine's shared pass against a forced gather and
     the single-host search (tests/dist_checks.check_sharded_shared_pass)."""
     _run_checks("check_sharded_shared_pass")
+
+
+@pytest.mark.timeout(600)
+def test_distributed_ladder_subprocess():
+    """The sharded search ends exact within two launches of the one ladder
+    (tests/dist_checks.check_distributed_ladder)."""
+    _run_checks("check_distributed_ladder")

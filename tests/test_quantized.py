@@ -3,7 +3,7 @@
 The tier's contract (docs/quantization.md): an int8 index's point set IS
 its decoded rows ``rows_view()``, and exact-mode search over the int8 tier
 returns the EXACT kNN of that point set — identical ids (recall@k = 1.0)
-to an fp32 BallForest built over the same decoded rows, in ``knn_search``,
+to an fp32 BallForest built over the same decoded rows, in ``knn``,
 ``knn_search_batch``, ``distributed_knn``, and a mutated
 ``SegmentedForest``, for all five Bregman families.  The lossy part is the
 storage round-off (bounded, applied once at ingest); the search pipeline
@@ -245,7 +245,7 @@ def test_quantized_budget_escalation_stays_exact():
     data, queries, fam = _dataset("squared_euclidean", n=400)
     qidx = build_index(data, "squared_euclidean", m=4, num_clusters=8,
                        seed=0, quantize=True)
-    res = search.knn_batch(qidx, queries, 5, budget=8, max_doublings=0)
+    res = search.knn_batch(qidx, queries, 5, budget=8)
     assert bool(jnp.all(res.exact))
     oracle_ids, _ = _decoded_oracle(qidx, queries, 5, fam)
     _assert_same_neighbors(res.ids, oracle_ids)

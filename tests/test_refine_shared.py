@@ -190,19 +190,18 @@ def test_shared_pass_matches_gather_around_threshold(index, side, passes,
 @pytest.mark.parametrize("impl", ["ref", "interpret"])
 def test_single_query_takes_shared_pass_at_budget_n(index, impl,
                                                     monkeypatch):
-    """The q = 1 path reads the whole table at budget n only, and returns
-    the batch path's neighbours."""
+    """A single query is the q = 1 row of the batch: its launch reads the
+    whole table at budget n only, and ``knn`` returns that row."""
     monkeypatch.setenv("REPRO_KERNEL_IMPL", impl)
     y = jnp.asarray(index.family.sample(jax.random.PRNGKey(8), (index.d,)))
     assert search.dense_refine(1, index.n, index.n)
     assert not search.dense_refine(1, index.n - 1, index.n)
-    # The jitted entry point's body, traced afresh for this backend.
-    body = search._knn_search_jit.__wrapped__
-    one = jax.jit(lambda ix, y: body(ix, y, 7, index.n))
-    text = one.lower(index, y).compile().as_text()
+    batch, text = _search(index, y[None], index.n, True)
     assert not re.search(r'/gather/', text)
-    res = one(index, y)
-    batch, _ = _search(index, y[None], index.n, True)
+    _, under = _search(index, y[None], index.n - 1, True)
+    assert GATHER.search(under)
+    res = search.knn(index, y, 7, budget=index.n)
+    assert res.ids.shape == (7,) and bool(res.exact)
     np.testing.assert_array_equal(np.asarray(res.ids),
                                   np.asarray(batch.ids[0]))
     np.testing.assert_allclose(np.asarray(res.dists),
@@ -245,7 +244,8 @@ def test_budget_n_launch_never_returns_tombstoned_rows(impl, monkeypatch):
 def test_knn_batch_records_dense_launches(index):
     """knn_batch's records flag the launches the rule sends to the shared
     pass: not the 8-slot first launch of 10 queries over 300 rows, the
-    escalated one; the full-scan escalation is never flagged."""
+    escalated one; a ladder whose last launch is at budget n reads the
+    table in shared passes."""
     fam = index.family
     ys = np.asarray(fam.sample(jax.random.PRNGKey(6), (10, index.d)))
     _, stats = search.knn_batch(index, ys, 5, budget=8, return_stats=True)
@@ -253,9 +253,10 @@ def test_knn_batch_records_dense_launches(index):
     assert flags == [search.dense_refine(10, r["budget"], index.n)
                      for r in stats.launches]
     assert flags[0] is False and flags[-1] is True
-    _, stats = search.knn_batch(index, ys, 5, budget=8, max_doublings=0,
+    _, stats = search.knn_batch(index, ys, 5, budget=index.n,
                                 return_stats=True)
-    assert stats.escalated_to_scan and stats.launches[-1]["dense"] is False
+    assert stats.launches[-1]["budget"] == index.n
+    assert stats.launches[-1]["dense"] is True
 
 
 def test_service_counts_dense_refines():

@@ -2,7 +2,7 @@
 
 The contract under test: a forest with live append segments and tombstones
 returns BIT-IDENTICAL kNN results to a freshly rebuilt forest over the
-same live points — in ``knn_search``, ``knn_search_batch``, and
+same live points — in ``knn``, ``knn_search_batch``, and
 ``distributed_knn`` (1x1 mesh) — with ``exact=True`` staying truthful;
 deleted ids never surface in any path; ``pad_points``/``slice_points``
 round-trip a mutated view; and compaction (merge or rebuild) preserves
@@ -78,8 +78,8 @@ def test_exact_bit_identical_to_fresh_rebuild(family):
                                   np.asarray(ref.dists))
     assert bool(jnp.all(res.exact)) and bool(jnp.all(ref.exact))
 
-    single = search.knn_search(sf, queries[0], K, budget)
-    single_ref = search.knn_search(fresh, queries[0], K, budget)
+    single = search.knn(sf, queries[0], K, budget)
+    single_ref = search.knn(fresh, queries[0], K, budget)
     np.testing.assert_array_equal(
         np.asarray(single.ids),
         np.asarray(orig_ids)[np.asarray(single_ref.ids)])
@@ -189,11 +189,11 @@ def test_exact_flag_truthful_under_tiny_budget():
 
 
 def test_budget_cap_escalation_skips_tombstones():
-    """The brute-force escape hatch must mask dead rows: with a starved
-    budget cap on a mutated forest, no deleted id (or -1) may surface."""
+    """The ladder's retry must mask dead rows: from a starved budget on a
+    mutated forest it ends exact, and no deleted id (or -1) may surface."""
     sf, fresh, orig_ids, queries = _mutated_setup("squared_euclidean",
                                                   seed=6)
-    res = search.knn_batch(sf, queries, K, budget=K, max_doublings=0)
+    res = search.knn_batch(sf, queries, K, budget=K)
     assert bool(jnp.all(res.exact))
     ids = np.asarray(res.ids)
     assert not np.isin(ids, list(DELETED)).any() and (ids >= 0).all()

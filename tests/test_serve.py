@@ -159,4 +159,3 @@ def test_knnlm_hook_exposes_escalation_stats(bundle, params):
     hook(jnp.zeros((2, bundle.cfg.vocab_size)), hidden)
     assert hook.escalations >= 0
     assert hook.budget_final >= 4          # >= k: the launch's real budget
-    assert hook.scan_fallbacks == 0

@@ -209,15 +209,14 @@ def test_public_entry_points_route_tiered_stores():
                                        jnp.float32(P_APPROX),
                                        block_rows=BLOCK_ROWS), ref_a)
 
-    # single-query wrappers slice the batched result to scalar shapes
-    one = search.knn_search(store, queries[0], K, BUDGET)
-    assert one.ids.shape == (K,)
-    np.testing.assert_array_equal(np.asarray(one.ids),
-                                  np.asarray(ref.ids)[0])
-    one_a = search.knn_search_approx(store, queries[0], K, BUDGET,
-                                     jnp.float32(P_APPROX))
-    np.testing.assert_array_equal(np.asarray(one_a.ids),
-                                  np.asarray(ref_a.ids)[0])
+    # a single query is the q = 1 row, sliced to scalar shapes, and
+    # climbs the same ladder as over the resident forest
+    one = search.knn(store, queries[0], K, BUDGET)
+    assert one.ids.shape == (K,) and bool(one.exact)
+    _assert_bitwise_equal(one, search.knn(index, queries[0], K, BUDGET))
+    one_a = search.knn(store, queries[0], K, BUDGET, approx_p=P_APPROX)
+    _assert_bitwise_equal(one_a, search.knn(index, queries[0], K, BUDGET,
+                                            approx_p=P_APPROX))
 
     # knn_batch retries/escalation accept a store
     res = search.knn_batch(store, queries, K, budget=BUDGET,
